@@ -15,6 +15,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (e2e_bench, fig456, kernels_bench, roofline,
                             serve_bench, table1)
     sections = {

@@ -784,6 +784,16 @@ def run_scaling(out_path: str = None) -> list[str]:
     import subprocess
     import sys
 
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        # a device belongs to one process: this one already holds it,
+        # so a child interpreter would fail or hang reaching it
+        raise RuntimeError(
+            f"run_scaling starts one child interpreter per device count, "
+            f"each with forced host devices; this process holds the "
+            f"{backend} devices, which no child could use.  Run it on "
+            f"the CPU backend (JAX_PLATFORMS=cpu).")
     out_path = out_path or os.path.join(os.getcwd(), "BENCH_serve.json")
     points = []
     for d in SCALING_DEVICE_COUNTS:

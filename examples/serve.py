@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch, reduced
 from repro.models import model
 from repro.runtime.serve import Request, ServingEngine
@@ -114,6 +115,7 @@ def main():
                          "XLA_FLAGS=--xla_force_host_platform_device_count"
                          "=N for a real N-device CPU mesh)")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = reduced(get_arch("granite-3-2b"), n_layers=2, d_model=128,
                   vocab=512)
     params = model.init(jax.random.PRNGKey(0), cfg, jnp.float32)

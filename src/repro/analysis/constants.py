@@ -22,8 +22,7 @@ DEFAULT_THRESHOLD_BYTES = 1 << 16     # 64 KiB
 
 
 def _subjaxprs(params: dict):
-    from jax.core import Jaxpr
-    from jax.extend.core import ClosedJaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for val in params.values():
         vals = val if isinstance(val, (tuple, list)) else (val,)
         for v in vals:

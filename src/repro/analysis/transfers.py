@@ -28,15 +28,15 @@ from repro.analysis.report import Finding, info, violation
 # primitives that move data to (or run code on) the host from inside a
 # compiled program; `infeed`/`outfeed` for completeness on TPU paths
 FORBIDDEN_PRIMITIVES = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
     "infeed", "outfeed",
 })
 
 
 def _subjaxprs(params: dict):
     """Yield every Jaxpr / ClosedJaxpr nested in an eqn's params."""
-    from jax.core import Jaxpr
-    from jax.extend.core import ClosedJaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for val in params.values():
         vals = val if isinstance(val, (tuple, list)) else (val,)
         for v in vals:

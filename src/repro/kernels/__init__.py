@@ -16,6 +16,8 @@
 #   chunk_attention — span-clamped fragment attention for the serving
 #                     tick (contiguous and paged variants)
 
+import re
+
 # Oracle/test pairing manifest: every kernel package must name the
 # interpret-mode test file (under tests/kernels/) that asserts it
 # allclose against its ref.py.  `python -m repro.analysis.lint`
@@ -29,3 +31,13 @@ KERNEL_TESTS = {
     "paged_attention": "test_paged_attention.py",
     "chunk_attention": "test_chunk_attention.py",
 }
+
+
+def tpu_kernel_names(compiled_text: str) -> set:
+    """Names of the Pallas kernels a compiled TPU program calls: the
+    ``tpu_custom_call`` instructions of ``Compiled.as_text()``, each
+    named after its ``pallas_call(name=...)`` (``paged_attention.3`` ->
+    ``paged_attention``)."""
+    return set(re.findall(
+        r"%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"", compiled_text))

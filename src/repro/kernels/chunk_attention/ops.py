@@ -5,11 +5,11 @@ point per layout; the width of the fragment picks the schedule, the
 charm_u50 way (``mm_large`` / ``mm_small`` chosen by the supervisor to
 match the fabric configuration to the job):
 
-* width <= ``NARROW_MAX_WIDTH``  ->  narrow kernel (all heads per
-  tile; the speculative verify fragment ``(n_slots, k+1)`` and other
-  skinny resumes)
-* wider fragments                ->  wide kernel (one GQA group per
-  tile; scheduler-chunk prefill)
+* width <= ``NARROW_MAX_WIDTH``  ->  narrow kernel (the whole fragment
+  in one tile; the speculative verify fragment ``(n_slots, k+1)`` and
+  other skinny resumes)
+* wider fragments                ->  wide kernel (query tiles of at most
+  ``WIDE_TILE_ROWS`` rows; scheduler-chunk and solo prefill)
 
 Width is a static shape, so the dispatch is resolved at trace time —
 each (width, layout) pair jits once and the tick graph contains only
@@ -18,7 +18,6 @@ the matching ``pallas_call``.
 from __future__ import annotations
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.chunk_attention.kernel import (
@@ -28,7 +27,7 @@ from repro.kernels.chunk_attention.kernel import (
     paged_chunk_attention_wide_call,
 )
 
-# Fragments at or below this width take the narrow (all-heads) kernel.
+# Fragments at or below this width take the narrow (one-tile) kernel.
 # The speculative verify width is k+1 (k in 2..6 across the configs
 # here); the scheduler chunk is 8+.
 NARROW_MAX_WIDTH = 8
@@ -82,9 +81,9 @@ def chunk_attention_kernel_sharded(q, k_cache, v_cache, q_pos, *,
     ``axis``; q_pos replicated.  Per-shard GQA ratio equals the global
     one, so narrow/wide tile shapes are valid on the slice."""
     hs = P(None, None, axis, None)
-    f = shard_map(chunk_attention_kernel, mesh=mesh,
-                  in_specs=(hs, hs, hs, P(None, None)), out_specs=hs,
-                  check_rep=False)
+    f = jax.shard_map(chunk_attention_kernel, mesh=mesh,
+                      in_specs=(hs, hs, hs, P(None, None)), out_specs=hs,
+                      check_vma=False)
     return f(q, k_cache, v_cache, q_pos)
 
 
@@ -94,11 +93,11 @@ def paged_chunk_attention_kernel_sharded(q, k_pages, v_pages, block_tables,
     """:func:`paged_chunk_attention_kernel` with pages head-sharded over
     ``axis``; block tables and positions replicated — every shard walks
     the same chain, reads its own head slice of each block."""
-    f = shard_map(paged_chunk_attention_kernel, mesh=mesh,
-                  in_specs=(P(None, None, axis, None),
-                            P(None, None, axis, None),
-                            P(None, None, axis, None),
-                            P(None, None), P(None, None)),
-                  out_specs=P(None, None, axis, None),
-                  check_rep=False)
+    f = jax.shard_map(paged_chunk_attention_kernel, mesh=mesh,
+                      in_specs=(P(None, None, axis, None),
+                                P(None, None, axis, None),
+                                P(None, None, axis, None),
+                                P(None, None), P(None, None)),
+                      out_specs=P(None, None, axis, None),
+                      check_vma=False)
     return f(q, k_pages, v_pages, block_tables, q_pos)

@@ -8,66 +8,21 @@ the §5.2 partial sum — children (KV blocks) stream their scores into the
 parent's running (max m, denominator l, accumulator acc) scratch, and
 HBM never sees a gathered contiguous copy of the sequence.
 
-The block table and per-slot lengths ride in as **scalar-prefetch**
-operands (``pltpu.PrefetchScalarGridSpec``): the BlockSpec index map
-reads ``tables[b, j]`` to aim each KV DMA at the right physical block —
-the address indirection is resolved by the supervisor-owned table, not
-by materializing the gather.
-
-Grid: (batch, kv_heads, blocks); the block dimension iterates
-sequentially on TPU, which makes the scratch carry legal.  All q heads
-of one GQA group are processed together (block shape (1, 1, group, D)).
+Decode is the one-token fragment of chunk attention: the query sits at
+position ``length - 1``, so the offset-causal mask ``kpos <= length - 1``
+is exactly the length mask.  The kernel is therefore the paged narrow
+chunk-attention schedule at width 1 (kernels/chunk_attention): block
+table and the per-row query position ride in as **scalar-prefetch**
+operands, the BlockSpec index map reads ``tables[b, j]`` to aim each KV
+DMA at the right physical block, all KV heads of a block come in one
+``(1, bs, Hkv, D)`` tile, and blocks past the chain are skipped.  A row
+of length 0 attends to nothing and reads out zeros.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-NEG_INF = -1e30
-
-
-def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc, m, l, *, block_size: int, sm_scale: float):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        m[...] = jnp.full_like(m, NEG_INF)
-        l[...] = jnp.zeros_like(l)
-
-    length = lens_ref[b]
-
-    # blocks past the chain (j·bs >= length) contribute nothing: skip the
-    # compute entirely — their table entries are NO_BLOCK (clamped to 0 by
-    # the index map) and their data is whatever the pool left there
-    @pl.when(j * block_size < length)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)        # (group, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)     # (bs, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)     # (bs, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * sm_scale
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)
-        s = jnp.where(pos < length, s, NEG_INF)    # (group, bs)
-        m_prev = m[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l[...] = l[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc[...] = acc[...] * alpha + jax.lax.dot(p, v)
-        m[...] = m_new
-
-    @pl.when(j == nb - 1)
-    def _readout():
-        o_ref[0, 0] = (acc[...] /
-                       jnp.maximum(l[...], 1e-30)).astype(o_ref.dtype)
+from repro.kernels.chunk_attention.kernel import (
+    paged_chunk_attention_narrow_call,
+)
 
 
 def paged_attention_call(q, k_pages, v_pages, block_tables, lengths, *,
@@ -75,42 +30,7 @@ def paged_attention_call(q, k_pages, v_pages, block_tables, lengths, *,
     """q: (B, H, D); k/v_pages: (P, bs, Hkv, D); block_tables: (B, NB)
     int32 (-1 = end of chain); lengths: (B,) valid tokens.  -> (B, H, D).
     """
-    b, h, d = q.shape
-    n_pages, block_size, hkv, _ = k_pages.shape
-    assert h % hkv == 0
-    group = h // hkv
-    nb = block_tables.shape[1]
-    sm_scale = 1.0 / (d ** 0.5)
-    q_r = q.reshape(b, hkv, group, d)
-
-    def q_map(ib, ih, j, tables, lens):
-        return (ib, ih, 0, 0)
-
-    def kv_map(ib, ih, j, tables, lens):
-        # the address indirection: table entry -> physical block
-        return (jnp.maximum(tables[ib, j], 0), 0, ih, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, d), q_map),
-            pl.BlockSpec((1, block_size, 1, d), kv_map),
-            pl.BlockSpec((1, block_size, 1, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, d), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((group, d), jnp.float32),   # acc
-            pltpu.VMEM((group, 1), jnp.float32),   # running max
-            pltpu.VMEM((group, 1), jnp.float32),   # denominator
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel, block_size=block_size,
-                          sm_scale=sm_scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q_r, k_pages, v_pages)
-    return out.reshape(b, h, d)
+    out = paged_chunk_attention_narrow_call(
+        q[:, None], k_pages, v_pages, block_tables, (lengths - 1)[:, None],
+        interpret=interpret, name="paged_attention")
+    return out[:, 0]

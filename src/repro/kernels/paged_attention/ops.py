@@ -2,7 +2,6 @@
 from __future__ import annotations
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.paged_attention.kernel import paged_attention_call
@@ -29,11 +28,11 @@ def paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths, *,
     replicated: every shard walks the same chain, reads its own head
     slice of each block.  Callers guard divisibility (``axis`` must
     divide H and Hkv) before routing here."""
-    f = shard_map(paged_attention, mesh=mesh,
-                  in_specs=(P(None, axis, None),
-                            P(None, None, axis, None),
-                            P(None, None, axis, None),
-                            P(None, None), P(None)),
-                  out_specs=P(None, axis, None),
-                  check_rep=False)
+    f = jax.shard_map(paged_attention, mesh=mesh,
+                      in_specs=(P(None, axis, None),
+                                P(None, None, axis, None),
+                                P(None, None, axis, None),
+                                P(None, None), P(None)),
+                      out_specs=P(None, axis, None),
+                      check_vma=False)
     return f(q, k_pages, v_pages, block_tables, lengths)

@@ -147,7 +147,6 @@ def _shard(x, axes):
 def moe_ffn_sharded(x, p, cfg, act: str, mesh):
     """x: (G, T, d).  Requires G divisible by the data axes product."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     e, k = cfg.n_experts, cfg.top_k
     gdim, t, d = x.shape
@@ -188,13 +187,13 @@ def moe_ffn_sharded(x, p, cfg, act: str, mesh):
         y = jax.lax.psum(y[:, :t], "model")
         return y, aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   P("model", "data", None), P("model", "data", None),
                   P("model", None, "data")),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     y = checkpoint_name(y, "moe_out")
 

@@ -16,7 +16,7 @@ def _violations(findings):
 
 
 def test_callback_smuggled_into_jaxpr_fires(make_spec):
-    # jax.debug.print compiles to a debug_callback primitive — a host
+    # jax.debug.print compiles to a debug_print primitive — a host
     # round-trip inside the tick.
     def step(params, tok, cache):
         jax.debug.print("tok {}", tok)
@@ -30,7 +30,7 @@ def test_callback_smuggled_into_jaxpr_fires(make_spec):
         donate_argnums=(2,))
     bad = _violations(audit_transfers(spec))
     assert bad, "a callback primitive inside the tick must be a violation"
-    assert any("debug_callback" in f.message for f in bad)
+    assert any("debug_print" in f.message for f in bad)
 
 
 def test_pure_callback_in_nested_scope_fires(make_spec):

@@ -105,12 +105,22 @@ def test_kernel_vs_ref_paged(c, case):
                                **TOL)
 
 
-def test_wide_schedule_vs_ref():
-    """Fragments above NARROW_MAX_WIDTH dispatch to the wide kernel."""
-    b, c, h, hkv, d, smax = 2, NARROW_MAX_WIDTH + 8, 8, 2, 64, 128
+@pytest.mark.parametrize("c,tiles", [(NARROW_MAX_WIDTH + 8, 1), (96, 2)],
+                         ids=["one_q_tile", "two_q_tiles"])
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+def test_wide_schedule_vs_ref(c, tiles, paged):
+    """Fragments above NARROW_MAX_WIDTH dispatch to the wide kernel,
+    which cuts them into query tiles of <= WIDE_TILE_ROWS rows."""
+    from repro.kernels.chunk_attention.kernel import _wide_q_tile
+    b, h, hkv, d, smax, bs = 2, 8, 2, 64, 128, 16
+    assert c // _wide_q_tile(c, h // hkv) == tiles
     q, kc, vc, q_pos = _inputs(b, c, h, hkv, d, smax, [0, 32], seed=3)
-    got = chunk_attention_kernel(q, kc, vc, q_pos)
     want = chunk_attention_ref(q, kc, vc, q_pos)
+    if paged:
+        kp, vp, tables = _paged_from_contiguous(kc, vc, bs, seed=3)
+        got = paged_chunk_attention_kernel(q, kp, vp, tables, q_pos)
+    else:
+        got = chunk_attention_kernel(q, kc, vc, q_pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
 
