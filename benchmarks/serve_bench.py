@@ -30,7 +30,7 @@ Measures the refactored engine on CPU-sized configs and writes
   forward; the non-speculative engine is exactly 1.0),
   ``acceptance_rate``, ``spec_decode_tokens_per_s`` vs
   ``baseline_decode_tokens_per_s`` — decode tokens per second of
-  serving-tick wall time (``ServingEngine.decode_wall_s``) on the same
+  serving-tick wall time (the engine's ``step()`` calls) on the same
   stream — plus the per-phase breakdown ``verify_forward_s`` /
   ``draft_s`` and ``spec_token_exact`` (greedy argmax verification is
   bit-exact — asserted on BOTH cache layouts).  Floors:
@@ -457,6 +457,24 @@ def _spec_requests(np, Request, n=8):
     return reqs
 
 
+def _time_steps(eng) -> list:
+    """Times every ``eng.step()`` call from now on (the serving ticks:
+    ``run_to_completion`` admits outside them); returns the one-element
+    list the seconds accumulate in."""
+    total = [0.0]
+    step = eng.step
+
+    def timed():
+        t = time.perf_counter()
+        try:
+            return step()
+        finally:
+            total[0] += time.perf_counter() - t
+
+    eng.step = timed
+    return total
+
+
 def run_spec(out_path: str = None) -> list[str]:
     import numpy as np
 
@@ -484,12 +502,13 @@ def run_spec(out_path: str = None) -> list[str]:
                                            max_new=6)])       # warm
             eng.reset_stats()
             reqs = _spec_requests(np, Request)
+            tick_s = _time_steps(eng)
             t0 = time.perf_counter()
             done, _ = eng.run_to_completion(reqs)
             dt = time.perf_counter() - t0
             assert len(done) == len(reqs)
             results[(spec, paged)] = dict(
-                engine=eng, dt=dt,
+                engine=eng, dt=dt, tick_s=tick_s[0],
                 outputs={r.rid: list(r.out) for r in done})
 
     # bit-exactness: speculative == non-speculative, on BOTH layouts
@@ -503,15 +522,17 @@ def run_spec(out_path: str = None) -> list[str]:
     base_eng = results[(False, False)]["engine"]
     spec_eng = results[(True, False)]["engine"]
     # decode wall-clock: tokens per second of *serving-tick* time (the
-    # engine's decode_wall_s — admission prefill excluded: identical
+    # engine's step() calls — admission prefill excluded: identical
     # work in both configs and, on CPU, dominated by per-prompt-bucket
     # XLA compiles that drown the decode signal; the whole-run number
     # stays in the record as run_tokens_per_s).  With the span-clamped
     # verify forward (kernels/chunk_attention and the jnp ladder) a
     # verify tick emits ~k+1 tokens for well under (k+1)x a decode
     # step, so speculation now wins wall-clock, not just forward count.
-    spec_tps = spec_eng.decode_tokens / max(spec_eng.decode_wall_s, 1e-9)
-    base_tps = base_eng.decode_tokens / max(base_eng.decode_wall_s, 1e-9)
+    spec_tps = spec_eng.decode_tokens \
+        / max(results[(True, False)]["tick_s"], 1e-9)
+    base_tps = base_eng.decode_tokens \
+        / max(results[(False, False)]["tick_s"], 1e-9)
 
     # per-phase timing: one jitted verify forward (width k+1) and one
     # drafter proposal on the bench config — where a spec tick's time

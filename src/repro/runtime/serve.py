@@ -106,9 +106,20 @@ Host Python keeps only what must be host-side: the rent/return ledger
 (`core/supervisor.CorePool`, itself a thin wrapper over the same jittable
 `runtime/pool` transitions), the prefix-hash map, the per-slot fragment
 cursors, the re-admission queue, and the request queue.
+
+**Spans.** Each ``step()`` records its host phases as
+``jax.profiler.TraceAnnotation`` spans, which an active profiler puts
+on the device trace's clock (and which cost about a microsecond each
+when none is): ``serve.tick`` around the whole step (``i``: ticks
+run), ``serve.admit`` (``queued``: frontier length), ``serve.schedule``,
+``serve.dispatch`` (the uploads and the jitted call; ``family``,
+``decode_rows``, ``frag_tokens``), ``serve.sync`` (the one
+``jax.device_get``; ``compiles``: programs compiled during the
+dispatch), ``serve.emit``, ``serve.preempt`` and ``serve.epilogue``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -117,6 +128,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.analysis import manifest as audit_manifest
@@ -137,6 +149,22 @@ NO_TOKEN = -1          # emitted-buffer sentinel: slot idle this iteration
 # recurrent state (ssm/hybrid) would absorb pad tokens, so those admit
 # one exact-length prompt per prefill call instead of a padded pack
 PACKED_PREFILL_FAMILIES = ("dense", "moe", "vlm")
+
+# Programs the backend compiled in this process, and the seconds that
+# took, fed by one jax.monitoring listener.  A load from the persistent
+# compilation cache is timed inside the same backend-compile event (its
+# own retrieval event nests in it), so this one event counts both.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiled = [0, 0.0]
+
+
+def _count_compile(event: str, duration: float, **_) -> None:
+    if event == _COMPILE_EVENT:
+        _compiled[0] += 1
+        _compiled[1] += duration
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
 
 
 def build_prefill_step(cfg: ArchConfig, max_seq: int,
@@ -1299,7 +1327,12 @@ class ServingEngine:
         self.baseline_syncs = 0
         self.device_ticks = 0
         self.decode_tokens = 0
-        self.decode_wall_s = 0.0   # wall time inside serving ticks
+        self.frag_tokens = 0       # prompt tokens prefilled through ticks
+        # programs compiled (or loaded from the persistent cache) during
+        # step(), and their seconds; a warmed engine compiles none
+        self.compiles = 0
+        self.compile_s = 0.0
+        self._dispatch_compiles = 0   # of the last dispatch, for its sync
         self.stalls = 0
         self.shared_block_hits = 0
         self.kv_bytes_allocated = 0
@@ -1649,6 +1682,7 @@ class ServingEngine:
         self.baseline_syncs += g
 
     # -- chunked prefill: fragment scheduler + unified tick ------------------
+    @functools.partial(annotate_function, name="serve.schedule")
     def _schedule_fragments(self, width: Optional[int] = None,
                             only_slot: Optional[int] = None):
         """Pick this tick's prompt fragments (host side): one fragment of
@@ -1739,11 +1773,17 @@ class ServingEngine:
             out = out + (fcols, frent)
         return out, finishing
 
-    def _refresh_block_mirrors(self, tables_d, ref_d) -> None:
-        """Host mirrors of the device block state, refreshed at every
-        paged tick sync — admission never blocks on the device."""
-        self._tables_host = np.asarray(tables_d).copy()
-        self._ref_host = np.asarray(ref_d).copy()
+    def _refresh_block_mirrors(self, stalls=0, tables=None,
+                               ref=None) -> None:
+        """Take in what a paged tick's sync brought back: its stall
+        count, and the host mirrors of the device block state, refreshed
+        at every sync — admission never blocks on the device.  A
+        contiguous tick's sync brings none of these."""
+        if tables is None:
+            return
+        self.stalls += int(stalls)
+        self._tables_host = np.asarray(tables).copy()
+        self._ref_host = np.asarray(ref).copy()
 
     def _decoding_slots(self) -> list[int]:
         """Active slots currently in the decode phase (not mid-prefill)."""
@@ -1832,93 +1872,63 @@ class ServingEngine:
         sched, finishing = self._schedule_fragments(
             width=self._solo_width, only_slot=slot)
         s1 = slice(slot, slot + 1)
-        if self.layout is None:
-            ft, fl, flast, fmax, _ = sched
-            self.dstate, self.cache, emitted = self._solo_fn(
-                self.params, self.dstate, self.cache, jnp.int32(slot),
-                jnp.asarray(ft[s1]), jnp.asarray(fl[s1]),
-                jnp.asarray(flast[s1]), jnp.asarray(fmax[s1]))
-            em, active_mask = jax.device_get((emitted, self.dstate.active))
-        else:
-            ft, fl, flast, fmax, fskip, fcols, frent = sched
-            (self.dstate, self.cache, self.bstate,
-             emitted) = self._solo_fn(
-                self.params, self.dstate, self.cache, self.bstate,
-                jnp.int32(slot), jnp.asarray(ft[s1]), jnp.asarray(fl[s1]),
-                jnp.asarray(flast[s1]), jnp.asarray(fmax[s1]),
-                jnp.asarray(fskip[s1]), jnp.asarray(fcols),
-                jnp.asarray(frent))
-            em, active_mask, tables_d, ref_d = jax.device_get(
-                (emitted, self.dstate.active, self.cache["block_tables"],
-                 self.bstate.refcount))
-            self._refresh_block_mirrors(tables_d, ref_d)
-        self.host_syncs += 1
+        ft, fl, flast, fmax, fskip = sched[:5]
+        with self._dispatch("solo_prefill", fl):
+            if self.layout is None:
+                self.dstate, self.cache, emitted = self._solo_fn(
+                    self.params, self.dstate, self.cache, jnp.int32(slot),
+                    jnp.asarray(ft[s1]), jnp.asarray(fl[s1]),
+                    jnp.asarray(flast[s1]), jnp.asarray(fmax[s1]))
+            else:
+                fcols, frent = sched[5:]
+                (self.dstate, self.cache, self.bstate,
+                 emitted) = self._solo_fn(
+                    self.params, self.dstate, self.cache, self.bstate,
+                    jnp.int32(slot), jnp.asarray(ft[s1]),
+                    jnp.asarray(fl[s1]), jnp.asarray(flast[s1]),
+                    jnp.asarray(fmax[s1]), jnp.asarray(fskip[s1]),
+                    jnp.asarray(fcols), jnp.asarray(frent))
+        em, active_mask, *block = self._sync(emitted, self.dstate.active)
         self.device_ticks += 1
-        fin = self._finish_jobs(finishing)
         finished: list[Request] = []
-        for s in finishing:                    # at most [slot]
-            req = self.active[s]
-            self._emit_row(req, s, em, fin)
-            if not active_mask[s]:             # max_new == 1 retires now
-                finished.append(req)
-                del self.active[s]
-                self._retire_slot(s, req)
+        with TraceAnnotation("serve.emit"):
+            self._refresh_block_mirrors(*block)
+            fin = self._finish_jobs(finishing)
+            for s in finishing:                    # at most [slot]
+                req = self.active[s]
+                self._emit_row(req, s, em, fin)
+                if not active_mask[s]:             # max_new == 1 retires now
+                    finished.append(req)
+                    del self.active[s]
+                    self._retire_slot(s, req)
         return finished
 
     def _spec_chunk_step(self) -> list[Request]:
         """Pure-decode speculation: up to ``chunk`` draft/verify/accept
         cycles inside one jitted loop — one host sync."""
-        if self.layout is None:
-            (self.dstate, self.draft_state, self.cache, emitted, fwd,
-             slot_fwd, drafted, accepted) = self._spec_chunk_fn(
-                self.params, self.dstate, self.draft_state, self.cache)
-            (em, active_mask, first, fwd, slot_fwd, drafted,
-             accepted) = jax.device_get(
-                (emitted, self.dstate.active, self._first, fwd, slot_fwd,
-                 drafted, accepted))
-        else:
-            (self.dstate, self.draft_state, self.cache, self.bstate,
-             emitted, fwd, slot_fwd, drafted, accepted,
-             stalls) = self._spec_chunk_fn(
-                self.params, self.dstate, self.draft_state, self.cache,
-                self.bstate)
-            (em, active_mask, first, fwd, slot_fwd, drafted, accepted,
-             stalls, tables_d, ref_d) = jax.device_get(
-                (emitted, self.dstate.active, self._first, fwd, slot_fwd,
-                 drafted, accepted, stalls, self.cache["block_tables"],
-                 self.bstate.refcount))
-            self._refresh_block_mirrors(tables_d, ref_d)
-            self.stalls += int(stalls)
-        self.host_syncs += 1
+        with self._dispatch("spec_chunk"):
+            if self.layout is None:
+                (self.dstate, self.draft_state, self.cache, emitted, fwd,
+                 slot_fwd, drafted, accepted) = self._spec_chunk_fn(
+                    self.params, self.dstate, self.draft_state, self.cache)
+                stalls = 0
+            else:
+                (self.dstate, self.draft_state, self.cache, self.bstate,
+                 emitted, fwd, slot_fwd, drafted, accepted,
+                 stalls) = self._spec_chunk_fn(
+                    self.params, self.dstate, self.draft_state, self.cache,
+                    self.bstate)
+        (em, active_mask, first, fwd, slot_fwd, drafted, accepted,
+         *block) = self._sync(emitted, self.dstate.active, self._first, fwd,
+                              slot_fwd, drafted, accepted, stalls=stalls)
         self.device_ticks += int(fwd)
         self.spec_forwards += int(fwd)
         self.spec_slot_forwards += int(slot_fwd)
         self.spec_drafted += int(drafted)
         self.spec_accepted += int(accepted)
-        finished: list[Request] = []
-        for slot, req in list(self.active.items()):
-            if slot in self._need_first:
-                req.out.append(int(first[slot]))
-                self._need_first.discard(slot)
-            row = self._checked_row(req, slot, em[slot])
-            new_toks = [int(t) for t in row if t != NO_TOKEN]
-            req.out.extend(new_toks)
-            self.decode_tokens += len(new_toks)
-            self.spec_decode_tokens += len(new_toks)
-            self.baseline_syncs += len(new_toks)
-            if not active_mask[slot]:
-                # hand off through _finished_instant and retire BEFORE
-                # dropping from `active`: if a corrupt ledger makes the
-                # release raise mid-loop, every request finished this
-                # tick is still reachable — rescued or drained by the
-                # fleet's quarantine, whose replay re-derives any tokens
-                # the raise discarded
-                self._finished_instant.append(req)
-                self._retire_slot(slot, req)
-                del self.active[slot]
-        finished += self._finished_instant
-        self._finished_instant = []
-        return finished
+        with TraceAnnotation("serve.emit"):
+            self._refresh_block_mirrors(*block)
+            return self._emit_rows(em, active_mask, first)
 
     def _spec_step(self) -> list[Request]:
         """One speculative tick: every DECODING slot drafts ahead and
@@ -1929,113 +1939,114 @@ class ServingEngine:
         # while prompt fragments (admission or resume) are outsourced
         assert self._jobs
         W = self._spec_width
-        decoding = self._decoding_slots()
+        n_decoding = len(self.active) - len(self._jobs)
         sched, finishing = self._schedule_fragments()
-        if self.layout is None:
-            ft, fl, flast, fmax, _ = sched
-        else:
-            ft, fl, flast, fmax, fskip, fcols, frent = sched
+        ft, fl, flast, fmax = sched[:4]
         if W > self._pchunk:
             ft = np.pad(ft, ((0, 0), (0, W - self._pchunk)))
-        if self.layout is None:
-            (self.dstate, self.draft_state, self.cache, emitted, drafted,
-             accepted) = self._spec_fn(
-                self.params, self.dstate, self.draft_state, self.cache,
-                jnp.asarray(ft), jnp.asarray(fl), jnp.asarray(flast),
-                jnp.asarray(fmax))
-            em, active_mask, first, drafted, accepted = jax.device_get(
-                (emitted, self.dstate.active, self._first, drafted,
-                 accepted))
-        else:
-            (self.dstate, self.draft_state, self.cache, self.bstate,
-             emitted, drafted, accepted, stalls) = self._spec_fn(
-                self.params, self.dstate, self.draft_state, self.cache,
-                self.bstate, jnp.asarray(ft), jnp.asarray(fl),
-                jnp.asarray(flast), jnp.asarray(fmax), jnp.asarray(fskip),
-                jnp.asarray(fcols), jnp.asarray(frent))
-            (em, active_mask, first, drafted, accepted, stalls, tables_d,
-             ref_d) = jax.device_get(
-                (emitted, self.dstate.active, self._first, drafted,
-                 accepted, stalls, self.cache["block_tables"],
-                 self.bstate.refcount))
-            self._refresh_block_mirrors(tables_d, ref_d)
-            self.stalls += int(stalls)
-        self.host_syncs += 1
+        with self._dispatch("spec", fl):
+            if self.layout is None:
+                (self.dstate, self.draft_state, self.cache, emitted,
+                 drafted, accepted) = self._spec_fn(
+                    self.params, self.dstate, self.draft_state, self.cache,
+                    jnp.asarray(ft), jnp.asarray(fl), jnp.asarray(flast),
+                    jnp.asarray(fmax))
+                stalls = 0
+            else:
+                fskip, fcols, frent = sched[4:]
+                (self.dstate, self.draft_state, self.cache, self.bstate,
+                 emitted, drafted, accepted, stalls) = self._spec_fn(
+                    self.params, self.dstate, self.draft_state, self.cache,
+                    self.bstate, jnp.asarray(ft), jnp.asarray(fl),
+                    jnp.asarray(flast), jnp.asarray(fmax),
+                    jnp.asarray(fskip), jnp.asarray(fcols),
+                    jnp.asarray(frent))
+        em, active_mask, first, drafted, accepted, *block = self._sync(
+            emitted, self.dstate.active, self._first, drafted, accepted,
+            stalls=stalls)
         self.device_ticks += 1
-        if decoding:
+        if n_decoding:
             self.spec_forwards += 1
-            self.spec_slot_forwards += len(decoding)
+            self.spec_slot_forwards += n_decoding
             self.spec_drafted += int(drafted)
             self.spec_accepted += int(accepted)
-        fin = self._finish_jobs(finishing)
-        finished: list[Request] = []
-        for slot, req in list(self.active.items()):
-            if slot in self._jobs:
-                continue               # mid-prefill: nothing emitted yet
-            if slot in self._need_first:
-                req.out.append(int(first[slot]))
-                self._need_first.discard(slot)
-            n_dec = self._emit_row(req, slot, em[slot], fin)
-            self.decode_tokens += n_dec
-            self.spec_decode_tokens += n_dec
-            self.baseline_syncs += n_dec
-            if not active_mask[slot]:
-                # hand off through _finished_instant and retire BEFORE
-                # dropping from `active`: if a corrupt ledger makes the
-                # release raise mid-loop, every request finished this
-                # tick is still reachable — rescued or drained by the
-                # fleet's quarantine, whose replay re-derives any tokens
-                # the raise discarded
-                self._finished_instant.append(req)
-                self._retire_slot(slot, req)
-                del self.active[slot]
-        finished += self._finished_instant
-        self._finished_instant = []
-        return finished
+        with TraceAnnotation("serve.emit"):
+            self._refresh_block_mirrors(*block)
+            return self._emit_rows(em, active_mask, first, finishing)
 
     def _mixed_step(self) -> list[Request]:
         """One unified prefill/decode tick: every PREFILLING slot eats a
         fragment, every DECODING slot one token; one host sync."""
         sched, finishing = self._schedule_fragments()
-        if self.layout is None:
-            ft, fl, flast, fmax, _ = sched
-            self.dstate, self.cache, emitted = self._mixed_fn(
-                self.params, self.dstate, self.cache, jnp.asarray(ft),
-                jnp.asarray(fl), jnp.asarray(flast), jnp.asarray(fmax))
-            em, active_mask, first = jax.device_get(
-                (emitted, self.dstate.active, self._first))
-        else:
-            ft, fl, flast, fmax, fskip, fcols, frent = sched
-            (self.dstate, self.cache, self.bstate, emitted,
-             stalls) = self._mixed_fn(
-                self.params, self.dstate, self.cache, self.bstate,
-                jnp.asarray(ft), jnp.asarray(fl), jnp.asarray(flast),
-                jnp.asarray(fmax), jnp.asarray(fskip), jnp.asarray(fcols),
-                jnp.asarray(frent))
-            em, active_mask, first, stalls, tables_d, ref_d = jax.device_get(
-                (emitted, self.dstate.active, self._first, stalls,
-                 self.cache["block_tables"], self.bstate.refcount))
-            self._refresh_block_mirrors(tables_d, ref_d)
-            self.stalls += int(stalls)
-        self.host_syncs += 1
+        ft, fl, flast, fmax = sched[:4]
+        with self._dispatch("mixed", fl):
+            if self.layout is None:
+                self.dstate, self.cache, emitted = self._mixed_fn(
+                    self.params, self.dstate, self.cache, jnp.asarray(ft),
+                    jnp.asarray(fl), jnp.asarray(flast), jnp.asarray(fmax))
+                stalls = 0
+            else:
+                fskip, fcols, frent = sched[4:]
+                (self.dstate, self.cache, self.bstate, emitted,
+                 stalls) = self._mixed_fn(
+                    self.params, self.dstate, self.cache, self.bstate,
+                    jnp.asarray(ft), jnp.asarray(fl), jnp.asarray(flast),
+                    jnp.asarray(fmax), jnp.asarray(fskip),
+                    jnp.asarray(fcols), jnp.asarray(frent))
+        em, active_mask, first, *block = self._sync(
+            emitted, self.dstate.active, self._first, stalls=stalls)
         self.device_ticks += 1
-        # PREFILL -> DECODE for finishing slots: the final fragment's
-        # argmax is the first token (what monolithic admission paid one
-        # sync for) — or, resuming, the replayed token dropped below
+        with TraceAnnotation("serve.emit"):
+            self._refresh_block_mirrors(*block)
+            return self._emit_rows(em, active_mask, first, finishing)
+
+    @contextlib.contextmanager
+    def _dispatch(self, family: str, frag_lens: Optional[np.ndarray] = None):
+        """``serve.dispatch`` around one tick's host->device uploads and
+        jitted call, ``frag_lens`` being its prompt fragments' lengths;
+        counts the programs compiled meanwhile for the tick's sync."""
+        frag = 0 if frag_lens is None else int(frag_lens.sum())
+        self.frag_tokens += frag
+        n0 = _compiled[0]
+        with TraceAnnotation("serve.dispatch", family=family,
+                             decode_rows=len(self.active) - len(self._jobs),
+                             frag_tokens=frag):
+            yield
+        self._dispatch_compiles = _compiled[0] - n0
+
+    def _sync(self, *arrays, stalls=0) -> tuple:
+        """``serve.sync``: the tick's one host sync, of ``arrays``.
+        Paged, the tick's ``stalls`` and the block state after it come
+        along, last, for :meth:`_refresh_block_mirrors`."""
+        if self.layout is not None:
+            arrays += (stalls, self.cache["block_tables"],
+                       self.bstate.refcount)
+        with TraceAnnotation("serve.sync", compiles=self._dispatch_compiles):
+            host = jax.device_get(arrays)
+        self.host_syncs += 1
+        return host
+
+    def _emit_rows(self, em, active_mask, first,
+                   finishing=()) -> list[Request]:
+        """Deliver a batched tick's synced rows: PREFILL -> DECODE for
+        the slots whose final fragment ran (its argmax is the first
+        token, or, resuming, the replayed token ``_emit_row`` drops),
+        then every slot past its prompt gets its row, and the slots the
+        tick retired are retired."""
         fin = self._finish_jobs(finishing)
-        finished: list[Request] = []
         for slot, req in list(self.active.items()):
             if slot in self._jobs:
                 continue               # mid-prefill: nothing emitted yet
             if slot in self._need_first:
-                # a monolithically admitted slot decoding through the
-                # mixed tick (resume jobs share it) delivers its
+                # a monolithically admitted slot delivers its
                 # admission-prefill first token here, in order
                 req.out.append(int(first[slot]))
                 self._need_first.discard(slot)
             n_dec = self._emit_row(req, slot, em[slot], fin)
             self.decode_tokens += n_dec
             self.baseline_syncs += n_dec
+            if self.spec:
+                self.spec_decode_tokens += n_dec
             if not active_mask[slot]:
                 # hand off through _finished_instant and retire BEFORE
                 # dropping from `active`: if a corrupt ledger makes the
@@ -2046,8 +2057,7 @@ class ServingEngine:
                 self._finished_instant.append(req)
                 self._retire_slot(slot, req)
                 del self.active[slot]
-        finished += self._finished_instant
-        self._finished_instant = []
+        finished, self._finished_instant = self._finished_instant, []
         return finished
 
     # -- one decode chunk over all active slots -----------------------------
@@ -2081,28 +2091,36 @@ class ServingEngine:
         re-admitted up front when the pool can take them back, and a
         tick that ran the pool dry (device stall or host scheduling
         shortfall) evicts one victim at the sync."""
+        compiled0 = tuple(_compiled)
         finished: list[Request] = []
-        if self._finished_instant:
-            # drained optimistically; a raise below restores them so the
-            # fleet's quarantine rescue still delivers them exactly once
-            finished, self._finished_instant = self._finished_instant, []
-        try:
-            finished = finished + self._tick()
-        except BaseException:
-            self._finished_instant = finished + self._finished_instant
-            raise
-        if self._frontier_rids:
-            self._frontier_epilogue(finished)
+        with TraceAnnotation("serve.tick", i=self.occ_ticks):
+            if self._finished_instant:
+                # drained optimistically; a raise below restores them so
+                # the fleet's quarantine rescue still delivers them
+                # exactly once
+                finished, self._finished_instant = \
+                    self._finished_instant, []
+            try:
+                finished = finished + self._tick()
+            except BaseException:
+                self._finished_instant = finished + self._finished_instant
+                raise
+            if self._frontier_rids:
+                self._frontier_epilogue(finished)
+        self.compiles += _compiled[0] - compiled0[0]
+        self.compile_s += _compiled[1] - compiled0[1]
         return finished
 
     def _tick(self) -> list[Request]:
         """One supervised tick: frontier admission, parked resume, the
         jitted device step, then over-commit pressure relief."""
         finished: list[Request] = []
-        if self._frontier or self._displaced:
-            self._admit_frontier()
-        if self._parked:
-            self._resume_parked(force=not self.active)
+        if self._frontier or self._displaced or self._parked:
+            with TraceAnnotation("serve.admit", queued=len(self._frontier)):
+                if self._frontier or self._displaced:
+                    self._admit_frontier()
+                if self._parked:
+                    self._resume_parked(force=not self.active)
         if not self.active:
             return finished
         if self._faults is not None:
@@ -2127,71 +2145,40 @@ class ServingEngine:
             finished += self._mixed_step()
         else:
             finished += self._decode_step()
-        # decode-phase wall clock: time spent inside serving ticks, i.e.
-        # excluding admission prefill and host queueing — the
-        # denominator of the bench's decode tokens/s (admission work is
-        # identical across engine configs and, on CPU, dominated by
-        # per-prompt-bucket XLA compiles that would drown the signal)
-        dt = time.perf_counter() - t0
-        self.decode_wall_s += dt
-        self.last_tick_wall_s = dt
+        self.last_tick_wall_s = time.perf_counter() - t0
         if self.overcommit and (self._pressure or self.stalls > stall_mark):
             # the tick ran the block pool dry: claw chains back until a
             # block actually came free — a fully-shared victim relieves
             # nothing (evict_chain frees 0), so parking it alone would
             # spend a replay without moving the pressure
             self._pressure = False
-            while True:
-                free0 = int(np.sum(self._ref_host == 0))
-                if self.preempt() is None:
-                    break
-                if int(np.sum(self._ref_host == 0)) > free0:
-                    break
+            with TraceAnnotation("serve.preempt"):
+                while True:
+                    free0 = int(np.sum(self._ref_host == 0))
+                    if self.preempt() is None:
+                        break
+                    if int(np.sum(self._ref_host == 0)) > free0:
+                        break
         return finished
 
     def _decode_step(self) -> list[Request]:
         """The multi-token decode chunk (no prefill fragments pending)."""
-        finished: list[Request] = []
-        if self.layout is None:
-            self.dstate, self.cache, emitted, iters = self._chunk_fn(
-                self.params, self.dstate, self.cache)
-            em, active_mask, first, iters = jax.device_get(
-                (emitted, self.dstate.active, self._first, iters))
-        else:
-            (self.dstate, self.cache, self.bstate, emitted, iters,
-             stalls) = self._chunk_fn(self.params, self.dstate, self.cache,
-                                      self.bstate)
-            (em, active_mask, first, iters, stalls, tables_d,
-             ref_d) = jax.device_get(
-                (emitted, self.dstate.active, self._first, iters, stalls,
-                 self.cache["block_tables"], self.bstate.refcount))
-            # refresh the host mirrors with the chunk's on-device growth
-            self._refresh_block_mirrors(tables_d, ref_d)
-            self.stalls += int(stalls)
-        self.host_syncs += 1
+        with self._dispatch("decode"):
+            if self.layout is None:
+                self.dstate, self.cache, emitted, iters = self._chunk_fn(
+                    self.params, self.dstate, self.cache)
+                stalls = 0
+            else:
+                (self.dstate, self.cache, self.bstate, emitted, iters,
+                 stalls) = self._chunk_fn(self.params, self.dstate,
+                                          self.cache, self.bstate)
+        em, active_mask, first, iters, *block = self._sync(
+            emitted, self.dstate.active, self._first, iters, stalls=stalls)
         self.device_ticks += int(iters)
-        for slot, req in list(self.active.items()):
-            if slot in self._need_first:
-                req.out.append(int(first[slot]))
-                self._need_first.discard(slot)
-            row = self._checked_row(req, slot, em[slot])
-            new_toks = [int(t) for t in row if t != NO_TOKEN]
-            req.out.extend(new_toks)
-            self.decode_tokens += len(new_toks)
-            self.baseline_syncs += len(new_toks)
-            if not active_mask[slot]:
-                # hand off through _finished_instant and retire BEFORE
-                # dropping from `active`: if a corrupt ledger makes the
-                # release raise mid-loop, every request finished this
-                # tick is still reachable — rescued or drained by the
-                # fleet's quarantine, whose replay re-derives any tokens
-                # the raise discarded
-                self._finished_instant.append(req)
-                self._retire_slot(slot, req)
-                del self.active[slot]
-        finished += self._finished_instant
-        self._finished_instant = []
-        return finished
+        with TraceAnnotation("serve.emit"):
+            # the chunk's on-device block growth refreshes the mirrors
+            self._refresh_block_mirrors(*block)
+            return self._emit_rows(em, active_mask, first)
 
     # -- preemption: evict under KV pressure, resume by replay --------------
     def _drop_chain_host(self, slot: int, evict: bool) -> None:
@@ -2454,6 +2441,7 @@ class ServingEngine:
         done, self._completed = self._completed, []
         return done
 
+    @functools.partial(annotate_function, name="serve.epilogue")
     def _frontier_epilogue(self, finished: list[Request]) -> None:
         """Post-tick SLO stamping + completion routing for
         frontier-submitted requests.  Host lists and one
@@ -2653,7 +2641,8 @@ class ServingEngine:
         warms nothing — then reset before the measured run."""
         self.host_syncs = self.baseline_syncs = 0
         self.device_ticks = self.decode_tokens = 0
-        self.decode_wall_s = 0.0
+        self.frag_tokens = self.compiles = 0
+        self.compile_s = 0.0
         self.stalls = 0
         self.shared_block_hits = 0
         self.kv_bytes_allocated = 0
@@ -2673,13 +2662,19 @@ class ServingEngine:
                 pool=pool._replace(peak_used=pool_lib.used(pool)))
 
     def sync_stats(self) -> dict:
-        """Host-sync economy vs a per-slot-per-tick engine (same run)."""
+        """Host-sync economy vs a per-slot-per-tick engine (same run),
+        the prompt tokens prefilled through ticks, and the programs
+        compiled (or loaded from the persistent cache) inside step()
+        with their seconds."""
         tokens = max(1, self.decode_tokens)
         return {
             "host_syncs": self.host_syncs,
             "baseline_syncs": self.baseline_syncs,
             "device_ticks": self.device_ticks,
             "decode_tokens": self.decode_tokens,
+            "frag_tokens": self.frag_tokens,
+            "compiles": self.compiles,
+            "compile_s": self.compile_s,
             "host_syncs_per_100_tokens": 100.0 * self.host_syncs / tokens,
             "baseline_syncs_per_100_tokens":
                 100.0 * self.baseline_syncs / tokens,
