@@ -10,8 +10,9 @@ configuration per problem shape):
   long the fragment.  Serves chunked prefill and resume replay, where
   the fragment is the scheduler chunk or the solo-prefill budget.
 * **narrow** — grid ``(batch, 1, kv_blocks)``: the whole fragment is
-  one tile.  Serves the speculative verify fragment ``(n_slots, k+1)``
-  and, at width 1, paged decode (kernels/paged_attention).
+  one tile.  Serves the speculative verify fragment ``(n_slots, k+1)``.
+  Paged decode, width 1, has a schedule of its own that walks only the
+  live pages (kernels/paged_attention).
 
 Both take *all* KV heads of a KV block in one ``(1, bs, Hkv, D)`` tile
 and contract it as a (Hkv, rows, bs) batched product over heads.  Mosaic
@@ -235,9 +236,8 @@ def paged_chunk_attention_wide_call(q, k_pages, v_pages, block_tables,
 
 
 def paged_chunk_attention_narrow_call(q, k_pages, v_pages, block_tables,
-                                      q_pos, *, interpret: bool = True,
-                                      name: str = "paged_chunk_attention"
-                                                  "_narrow"):
+                                      q_pos, *, interpret: bool = True):
     return _chunk_call(q, k_pages, v_pages, q_pos, block_tables,
                        kv_block=k_pages.shape[1], q_tile=q.shape[1],
-                       name=name, interpret=interpret)
+                       name="paged_chunk_attention_narrow",
+                       interpret=interpret)

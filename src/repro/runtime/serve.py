@@ -113,7 +113,7 @@ on the device trace's clock (and which cost about a microsecond each
 when none is): ``serve.tick`` around the whole step (``i``: ticks
 run), ``serve.admit`` (``queued``: frontier length), ``serve.schedule``,
 ``serve.dispatch`` (the uploads and the jitted call; ``family``,
-``decode_rows``, ``frag_tokens``), ``serve.sync`` (the one
+``decode_rows``, ``frag_tokens``, ``kv_pages``), ``serve.sync`` (the one
 ``jax.device_get``; ``compiles``: programs compiled during the
 dispatch), ``serve.emit``, ``serve.preempt`` and ``serve.epilogue``.
 """
@@ -1195,7 +1195,11 @@ class ServingEngine:
         self.dstate = init_decode_state(n_slots)
         self._first = jnp.zeros((n_slots,), jnp.int32)
         self._need_first: set[int] = set()
-        self._chunk_fn = build_decode_chunk(cfg, chunk=chunk, eos_id=eos_id,
+        # pos + 1 of each slot outside `active` as the device keeps it,
+        # which the paged decode kernel still walks: a retired row's
+        # frozen length, 1 for a slot never used or parked
+        self._idle_len = np.ones((n_slots,), np.int64)
+        self._chunk_fn =build_decode_chunk(cfg, chunk=chunk, eos_id=eos_id,
                                             rules=rules, decode_fn=decode_fn,
                                             paged=self.layout)
         if self.layout is None:
@@ -1328,6 +1332,9 @@ class ServingEngine:
         self.device_ticks = 0
         self.decode_tokens = 0
         self.frag_tokens = 0       # prompt tokens prefilled through ticks
+        # paged: KV pages the decode kernel walks at each chunk's first
+        # step, over every row
+        self.decode_kv_pages = 0
         # programs compiled (or loaded from the persistent cache) during
         # step(), and their seconds; a warmed engine compiles none
         self.compiles = 0
@@ -2001,16 +2008,19 @@ class ServingEngine:
             return self._emit_rows(em, active_mask, first, finishing)
 
     @contextlib.contextmanager
-    def _dispatch(self, family: str, frag_lens: Optional[np.ndarray] = None):
+    def _dispatch(self, family: str, frag_lens: Optional[np.ndarray] = None,
+                  kv_pages: int = 0):
         """``serve.dispatch`` around one tick's host->device uploads and
-        jitted call, ``frag_lens`` being its prompt fragments' lengths;
+        jitted call, ``frag_lens`` being its prompt fragments' lengths
+        and ``kv_pages`` the KV pages its decode kernel walks;
         counts the programs compiled meanwhile for the tick's sync."""
         frag = 0 if frag_lens is None else int(frag_lens.sum())
         self.frag_tokens += frag
+        self.decode_kv_pages += kv_pages
         n0 = _compiled[0]
         with TraceAnnotation("serve.dispatch", family=family,
                              decode_rows=len(self.active) - len(self._jobs),
-                             frag_tokens=frag):
+                             frag_tokens=frag, kv_pages=kv_pages):
             yield
         self._dispatch_compiles = _compiled[0] - n0
 
@@ -2161,9 +2171,29 @@ class ServingEngine:
                         break
         return finished
 
+    def _kv_len(self, slot: int, req: Request) -> int:
+        """``pos + 1`` of an active row at its next decode step: the
+        prompt and every emitted token, the newest written by that
+        step."""
+        return (len(req.prompt) + self._offset + len(req.out)
+                + (slot in self._need_first))
+
+    def _kv_pages_walked(self) -> int:
+        """Paged: the KV pages the decode kernel walks at a chunk's first
+        step, ``ceil(min(pos + 1, max_seq) / block_size)`` over every
+        slot, active or not, from the host's state."""
+        if self.layout is None:
+            return 0
+        bs = self.layout.block_size
+        lens = self._idle_len.copy()
+        for slot, req in self.active.items():
+            lens[slot] = self._kv_len(slot, req)
+        nb = -(-self.max_seq // bs)
+        return int(np.sum(np.minimum(-(-lens // bs), nb)))
+
     def _decode_step(self) -> list[Request]:
         """The multi-token decode chunk (no prefill fragments pending)."""
-        with self._dispatch("decode"):
+        with self._dispatch("decode", kv_pages=self._kv_pages_walked()):
             if self.layout is None:
                 self.dstate, self.cache, emitted, iters = self._chunk_fn(
                     self.params, self.dstate, self.cache)
@@ -2248,6 +2278,7 @@ class ServingEngine:
         self.dstate = self.dstate._replace(
             active=self.dstate.active.at[slot].set(False))
         self.cache["pos"] = self.cache["pos"].at[slot].set(0)
+        self._idle_len[slot] = 1
         if self.layout is not None:
             self._drop_chain_host(slot, evict=True)
         if self.spec:
@@ -2327,6 +2358,7 @@ class ServingEngine:
             self.kv_bytes_allocated += self._slot_bytes
             self.pool.release(slot)
             return
+        self._idle_len[slot] = self._kv_len(slot, req)
         self._drop_chain_host(slot, evict=False)
         self.pool.release(slot)
 
@@ -2642,6 +2674,7 @@ class ServingEngine:
         self.host_syncs = self.baseline_syncs = 0
         self.device_ticks = self.decode_tokens = 0
         self.frag_tokens = self.compiles = 0
+        self.decode_kv_pages = 0
         self.compile_s = 0.0
         self.stalls = 0
         self.shared_block_hits = 0
@@ -2663,9 +2696,10 @@ class ServingEngine:
 
     def sync_stats(self) -> dict:
         """Host-sync economy vs a per-slot-per-tick engine (same run),
-        the prompt tokens prefilled through ticks, and the programs
-        compiled (or loaded from the persistent cache) inside step()
-        with their seconds."""
+        the prompt tokens prefilled through ticks, the KV pages the
+        decode kernel walked at each chunk's first step (paged),
+        and the programs compiled (or loaded from the persistent cache)
+        inside step() with their seconds."""
         tokens = max(1, self.decode_tokens)
         return {
             "host_syncs": self.host_syncs,
@@ -2673,6 +2707,7 @@ class ServingEngine:
             "device_ticks": self.device_ticks,
             "decode_tokens": self.decode_tokens,
             "frag_tokens": self.frag_tokens,
+            "decode_kv_pages": self.decode_kv_pages,
             "compiles": self.compiles,
             "compile_s": self.compile_s,
             "host_syncs_per_100_tokens": 100.0 * self.host_syncs / tokens,

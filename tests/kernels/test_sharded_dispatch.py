@@ -6,8 +6,12 @@ mesh the kernels run per-shard on their local head slice via
 attention, so each shard executes literally the same program the
 unsharded kernel runs on that head slice — the outputs must match to
 the bit, and the width-picks-the-schedule dispatch must be unchanged
-(the fragment axis is unsharded).  Cells skip on a single-device host;
-CI runs them under the forced multi-device step.
+(the fragment axis is unsharded).  The paged decode kernel contracts a
+page group's rows of all the KV heads it is given at once, so its
+whole-width call sums in another order than a shard's: its twin is held
+to the unsharded kernel run on each shard's head slice.  Cells skip on
+a single-device host; CI runs them under the forced multi-device
+step.
 """
 from __future__ import annotations
 
@@ -83,7 +87,13 @@ def test_paged_attention_sharded_bit_exact(mesh, data):
     q, _ = _q(rng, 1)
     q1 = q[:, 0]
     lengths = jnp.asarray(rng.integers(4, SMAX, size=(B,)), jnp.int32)
-    ref = paged_attention(q1, kp, vp, bt, lengths)
+    n = mesh.shape["model"]
+    h, hkv = H // n, HKV // n
+    ref = jnp.concatenate(
+        [paged_attention(q1[:, i * h:(i + 1) * h],
+                         kp[:, :, i * hkv:(i + 1) * hkv],
+                         vp[:, :, i * hkv:(i + 1) * hkv], bt, lengths)
+         for i in range(n)], axis=1)
     out = paged_attention_sharded(q1, kp, vp, bt, lengths, mesh=mesh)
     assert jnp.array_equal(ref, out)
 

@@ -1,5 +1,7 @@
 """Compile the served attention kernels for a TPU v5e that is described,
-not attached, at granite-3-2b widths (H 32, Hkv 8, D 64, block 16, bf16).
+not attached, at granite-3-2b widths (H 32, Hkv 8, D 64, block 16, bf16),
+and the paged decode kernel also at the D 128 widths of granite-8b,
+starcoder2-3b and moonshot-v1-16b-a3b.
 
 Interpret mode runs a kernel's math but not Mosaic's rules: tiling
 (a block's last two dimensions must be (8, 128)-divisible or whole),
@@ -24,10 +26,13 @@ import pytest
 from repro.kernels import tpu_kernel_names
 
 H, HKV, D = 32, 8, 64                 # configs/granite_3_2b.py
+GRANITE = (H, HKV, D)
 SLOTS, MAX_SEQ, BS = 8, 2048, 16      # chip_smoke.py's serving shape
 PAGES = SLOTS * MAX_SEQ // BS
 NB = MAX_SEQ // BS
 WIDE, NARROW = 128, 5                 # solo-prefill width, spec_k + 1
+SMOKE = (SLOTS, MAX_SEQ, PAGES)
+BENCH = (16, 4096, 768)               # the on-chip benchmark's deployment
 
 
 @pytest.fixture(scope="module")
@@ -50,32 +55,48 @@ def topo():
         compilation_cache.reset_cache()
 
 
-def _args(sharding, c, paged):
+def _args(sharding, c, paged, shape=SMOKE, widths=GRANITE):
+    slots, max_seq, pages = shape
+    h, hkv, d = widths
     def s(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-    kv = (PAGES, BS, HKV, D) if paged else (SLOTS, MAX_SEQ, HKV, D)
-    q = (SLOTS, H, D) if c is None else (SLOTS, c, H, D)
+    kv = (pages, BS, hkv, d) if paged else (slots, max_seq, hkv, d)
+    q = (slots, h, d) if c is None else (slots, c, h, d)
     args = [s(q), s(kv), s(kv)]
     if paged:
-        args.append(s((SLOTS, NB), jnp.int32))
-    args.append(s((SLOTS,) if c is None else (SLOTS, c), jnp.int32))
+        args.append(s((slots, max_seq // BS), jnp.int32))
+    args.append(s((slots,) if c is None else (slots, c), jnp.int32))
     return args
 
 
-@pytest.mark.parametrize("name,c,paged", [
-    ("paged_attention", None, True),
-    ("chunk_attention_wide", WIDE, False),
-    ("chunk_attention_narrow", NARROW, False),
-    ("paged_chunk_attention_wide", WIDE, True),
-    ("paged_chunk_attention_narrow", NARROW, True),
+@pytest.mark.parametrize("name,c,paged,shape,widths", [
+    pytest.param("paged_attention", None, True, SMOKE, GRANITE,
+                 id="paged_attention-None-True"),
+    pytest.param("paged_attention", None, True, BENCH, GRANITE,
+                 id="paged_attention-None-True-16x4096"),
+    pytest.param("paged_attention", None, True, SMOKE, (32, 8, 128),
+                 id="paged_attention-None-True-granite-8b"),
+    pytest.param("paged_attention", None, True, SMOKE, (24, 2, 128),
+                 id="paged_attention-None-True-starcoder2-3b"),
+    pytest.param("paged_attention", None, True, SMOKE, (16, 16, 128),
+                 id="paged_attention-None-True-moonshot"),
+    pytest.param("chunk_attention_wide", WIDE, False, SMOKE, GRANITE,
+                 id="chunk_attention_wide-128-False"),
+    pytest.param("chunk_attention_narrow", NARROW, False, SMOKE, GRANITE,
+                 id="chunk_attention_narrow-5-False"),
+    pytest.param("paged_chunk_attention_wide", WIDE, True, SMOKE, GRANITE,
+                 id="paged_chunk_attention_wide-128-True"),
+    pytest.param("paged_chunk_attention_narrow", NARROW, True, SMOKE,
+                 GRANITE, id="paged_chunk_attention_narrow-5-True"),
 ])
-def test_kernel_compiles_for_v5e(topo, name, c, paged):
+def test_kernel_compiles_for_v5e(topo, name, c, paged, shape, widths):
     from jax.sharding import SingleDeviceSharding
     from repro.kernels.chunk_attention import kernel as chunk_kernel
     from repro.kernels.paged_attention import kernel as paged_kernel
     module = paged_kernel if name == "paged_attention" else chunk_kernel
     call = getattr(module, f"{name}_call")
-    args = _args(SingleDeviceSharding(topo.devices[0]), c, paged)
+    args = _args(SingleDeviceSharding(topo.devices[0]), c, paged, shape,
+                 widths)
     compiled = jax.jit(
         lambda *a: call(*a, interpret=False)).lower(*args).compile()
     assert tpu_kernel_names(compiled.as_text()) == {name}

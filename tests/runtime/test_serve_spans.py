@@ -5,8 +5,9 @@
 prefill engine is driven under ``jax.profiler`` on the CPU and the
 spans are read with ``jax.profiler.ProfileData``: one ``serve.tick`` per
 step, dispatch -> sync -> emit nested in order inside it, the dispatched
-family and fragment tokens as the engine took them, and compiles counted
-on a fresh engine's first tick and on no repeat of the same shapes.
+family, fragment tokens and decode KV pages as the engine took them, and
+compiles counted on a fresh engine's first tick and on no repeat of the
+same shapes.
 """
 import glob
 import os
@@ -23,6 +24,7 @@ FAMILY_ATTRS = {"_chunk_fn": "decode", "_mixed_fn": "mixed",
                 "_solo_fn": "solo_prefill", "_spec_fn": "spec",
                 "_spec_chunk_fn": "spec_chunk"}
 PROMPTS = (20, 13)          # the first prefills alone, the second mixed
+BLOCK = 8
 
 
 def record_families(eng) -> list:
@@ -37,6 +39,23 @@ def record_families(eng) -> list:
                 return fn(*args)
             setattr(eng, attr, tick)
     return calls
+
+
+def record_live_pages(eng) -> list:
+    """Wraps the decode chunk: for each call, the KV pages the paged
+    decode kernel walks at the first step, ``ceil((pos + 1) / block)``
+    over every row, active or not, clipped to the table's width, read
+    from the device state it is given."""
+    pages = []
+    fn = eng._chunk_fn
+
+    def tick(params, state, cache, *rest):
+        pos = np.asarray(cache["pos"])
+        nb = cache["block_tables"].shape[1]
+        pages.append(int(np.sum(np.minimum(-(-(pos + 1) // BLOCK), nb))))
+        return fn(params, state, cache, *rest)
+    eng._chunk_fn = tick
+    return pages
 
 
 def serve_two(eng, rng) -> tuple:
@@ -83,10 +102,11 @@ def traced(request, serve_setup, tmp_path_factory):
     traced; the stats are reset between the rounds."""
     cfg, params = serve_setup
     eng = ServingEngine(params, cfg, n_slots=2, max_seq=64, chunk=4,
-                        paged=True, block_size=8, n_blocks=24,
+                        paged=True, block_size=BLOCK, n_blocks=24,
                         prefix_sharing=False, chunked_prefill=True,
                         prefill_chunk_tokens=8, speculative=request.param)
     calls = record_families(eng)
+    pages = record_live_pages(eng)
     rng = np.random.default_rng(11)
     directory = str(tmp_path_factory.mktemp("trace"))
     options = jax.profiler.ProfileOptions()
@@ -98,7 +118,8 @@ def traced(request, serve_setup, tmp_path_factory):
             steps, reqs, first = serve_two(eng, rng)
             rounds.append(dict(steps=steps, reqs=reqs, first=first,
                                stats=eng.sync_stats(),
-                               n_calls=len(calls)))
+                               n_calls=len(calls), pages=list(pages)))
+            pages.clear()
             eng.reset_stats()
     finally:
         jax.profiler.stop_trace()
@@ -153,6 +174,24 @@ def test_family_and_frag_tokens_match_the_path_taken(traced):
         assert r["stats"]["frag_tokens"] == len(a.prompt) + len(b.prompt)
 
 
+def test_kv_pages_are_the_live_pages_of_the_decode_rows(traced):
+    """``kv_pages`` on each decode dispatch, and ``decode_kv_pages`` in
+    the stats, sum the pages the paged decode kernel walks at the
+    chunk's first step: every row's, a retired row's at its frozen
+    length; no other family walks the paged decode kernel."""
+    dispatches = [s for s in traced["spans"] if s[0] == "serve.dispatch"]
+    decode = [s[3]["kv_pages"] for s in dispatches
+              if s[3]["family"] == "decode"]
+    assert all(s[3]["kv_pages"] == 0 for s in dispatches
+               if s[3]["family"] != "decode")
+    pages = [p for r in traced["rounds"] for p in r["pages"]]
+    assert decode == pages
+    if not traced["spec"]:
+        assert pages and all(p > 0 for p in pages)
+    for r in traced["rounds"]:
+        assert r["stats"]["decode_kv_pages"] == sum(r["pages"])
+
+
 def test_compiles_on_a_fresh_engine_and_not_on_a_repeat(traced):
     syncs = [s for s in traced["spans"] if s[0] == "serve.sync"]
     n_first = traced["rounds"][0]["steps"]
@@ -167,5 +206,5 @@ def test_compiles_on_a_fresh_engine_and_not_on_a_repeat(traced):
 
 def test_reset_stats_zeroes_the_new_counters(traced):
     stats = traced["stats"]
-    assert (stats["frag_tokens"], stats["compiles"],
-            stats["compile_s"]) == (0, 0, 0.0)
+    assert (stats["frag_tokens"], stats["decode_kv_pages"],
+            stats["compiles"], stats["compile_s"]) == (0, 0, 0, 0.0)
