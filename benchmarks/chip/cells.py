@@ -3,8 +3,12 @@ traffic mix, limit or per-layer metric is added by adding files only.
 
 * ``BENCHMARK.json`` at the checkout root: the cells, the metrics and
   which cells each metric is read in;
-* ``configs/<config>.json``: the model and its deployment (the path is
-  the config entry's ``file`` in ``BENCHMARK.json``);
+* ``configs/<config>.json``: the model, its deployment and, under
+  ``arch``, the name of its architecture module (the path is the config
+  entry's ``file`` in ``BENCHMARK.json``);
+* ``archs/<arch>.py``: what depends on the block (``ARCH_API``): the
+  weights' shapes, the float32 reference and its float8 control, and
+  the work counts;
 * ``traffic/<mix>.json``: the traffic mix (see ``traffic.py``);
 * ``limits/<cell>.json``: the limit of each number the correctness
   check compares, with the readings it was set from;
@@ -14,12 +18,21 @@ traffic mix, limit or per-layer metric is added by adding files only.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+
+# what an architecture module gives: path -> (shape, std) of every weight;
+# the float32 reference's logits and their float8 control; weights
+# multiplied per token; attention FLOPs of one query over a context; KV
+# bytes of one position
+ARCH_API = ("shapes", "logits", "matmul_params", "attention_flops",
+            "kv_bytes_per_position")
 
 
 @dataclasses.dataclass
@@ -27,6 +40,7 @@ class Cell:
     name: str
     chips: int
     config: dict            # configs/<config>.json
+    arch: ModuleType        # archs/<config["arch"]>.py
     mix: dict               # traffic/<mix>.json, its base merged in
     limits: dict            # limits/<cell>.json
     end_to_end: list        # BENCHMARK.json metrics this cell reports
@@ -52,8 +66,9 @@ def _reports(metric: dict, cell: str) -> bool:
 
 def find_cell(name: str, root: Path = ROOT, here: Path = HERE) -> Cell:
     """The cell ``name`` of ``<root>/BENCHMARK.json`` with its files:
-    configuration files by their path from ``root``, traffic mixes and
-    limits from ``here``."""
+    configuration files by their path from ``root``, architecture
+    modules, traffic mixes and limits from ``here``.  A configuration
+    that names no ``arch`` is an error."""
     bench = load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -61,13 +76,33 @@ def find_cell(name: str, root: Path = ROOT, here: Path = HERE) -> Cell:
                        f"{sorted(cells)}")
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
+    file = root / configs[w["config"]]["file"]
+    config = load_json(file)
+    if "arch" not in config:
+        raise ValueError(f"{file} names no \"arch\": the architecture "
+                         f"module archs/<arch>.py that builds its block")
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=load_json(root / configs[w["config"]]["file"]),
+        name=name, chips=int(w["chips"]), config=config,
+        arch=load_arch(config["arch"], here / "archs"),
         mix=load_mix(w["traffic"], here / "traffic"),
         limits=load_json(here / "limits" / f"{name}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _reports(m, name)],
         per_layer=[m for m in bench["per_layer"] if _reports(m, name)])
+
+
+@functools.lru_cache(maxsize=None)
+def load_arch(name: str, directory: Path = HERE / "archs") -> ModuleType:
+    """``archs/<name>.py``, checked to give every name of ``ARCH_API``.
+    Loaded once per file, so that its jitted reference compiles once."""
+    path = directory / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(
+        f"benchmarks.chip.archs.{name.replace('.', '_')}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    missing = [k for k in ARCH_API if not callable(getattr(module, k, None))]
+    if missing:
+        raise ValueError(f"{path} gives no {', '.join(missing)}")
+    return module
 
 
 def load_reader(metric: str, directory: Path = HERE / "metrics"):

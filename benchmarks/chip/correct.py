@@ -1,6 +1,7 @@
 """Whether what the timed path served is right: a comparison of the
-served tokens with the float32 reference, and the control that it has
-to fail.
+served tokens with the float32 reference of the configuration's
+architecture module (``archs/<arch>.py``, its ``logits``), and the
+control that it has to fail.
 
 Once the window has closed, a sample of the finished requests, drawn
 from the seed and always holding the one that served the most tokens,
@@ -33,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.chip import reference
 from benchmarks.chip.traffic import seed_rng
 
 MIN_REQUESTS = 4
@@ -86,9 +86,10 @@ def served_positions(req) -> tuple:
     return tokens, out, rows
 
 
-def gaps(m: dict, params: dict, requests: list,
+def gaps(arch, m: dict, params: dict, requests: list,
          control: bool = False) -> dict:
-    """Gap readings over ``requests``, by who served the tokens:
+    """Gap readings over ``requests`` of model ``m`` under architecture
+    module ``arch``, by who served the tokens:
     ``{"program": reading}`` and, with ``control``, ``"control"``: the
     float8 reference's own choices at the same positions.  A reading
     holds the ``mean`` and the ``widest`` gap and the ``tokens``
@@ -98,13 +99,13 @@ def gaps(m: dict, params: dict, requests: list,
         parts["control"] = []
     for req in requests:
         tokens, served, rows = served_positions(req)
-        ref = reference.logits(m, params, tokens)
+        ref = arch.logits(m, params, tokens)
         target = np.zeros(ref.shape[0], np.int32)
         target[rows] = served
         parts["program"].append(
             np.asarray(_gaps(ref, jnp.asarray(target)))[rows])
         if control:
-            low = _top(reference.logits(m, params, tokens, "fp8"))
+            low = _top(arch.logits(m, params, tokens, "fp8"))
             parts["control"].append(np.asarray(_gaps(ref, low))[rows])
         del ref
     out = {}

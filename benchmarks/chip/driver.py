@@ -18,6 +18,7 @@ the device's time and its idle gaps to what the host was doing.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 from typing import Callable, Optional
 
@@ -69,6 +70,10 @@ class Window:
     ticks: list                     # TickCall of every step
     tokens_in_window: int
     steps: int
+    # host seconds of each step() that returned inside the window
+    step_s: list = dataclasses.field(default_factory=list)
+    # seconds of each garbage collection pass inside the window
+    gc_s: list = dataclasses.field(default_factory=list)
 
 
 class TickLog:
@@ -180,6 +185,17 @@ def run_window(frontier, specs: list, mix: dict, *, seconds: float,
     recs: dict = {}
     inflight: list = []
     tokens_in_window = 0
+    step_s: list = []
+    gc_s: list = []
+    gc_start: list = []
+
+    def on_gc(phase: str, info: dict) -> None:
+        if phase == "start":
+            gc_start[:] = [time.perf_counter()]
+        elif gc_start and clock() < t_close:
+            gc_s.append(time.perf_counter() - gc_start[0])
+
+    gc.callbacks.append(on_gc)
 
     def send(spec, due: float, now: float) -> None:
         req = make_request(spec)
@@ -205,9 +221,12 @@ def run_window(frontier, specs: list, mix: dict, *, seconds: float,
                         spec = due.pop()
                         send(spec, t_open + spec.due_s, clock())
         if frontier.has_work:
+            before = clock()
             with TraceAnnotation("bench.step", i=log.step):
                 frontier.step()
             seen = clock()
+            if seen <= t_close:
+                step_s.append(seen - before)
             log.settle()
             with TraceAnnotation("bench.poll"):
                 done = {id(r) for r in frontier.poll()}
@@ -244,8 +263,10 @@ def run_window(frontier, specs: list, mix: dict, *, seconds: float,
                 wake = min(wake, t_open + hooks[-1][0])
             with TraceAnnotation("bench.idle"):
                 sleep(max(0.0, wake - clock()))
+    gc.callbacks.remove(on_gc)
     for _, fn in reversed(hooks):       # a hook past the end still runs
         fn()
     return Window(open=t_open, close=t_close, end=clock(),
                   recs=list(recs.values()), ticks=log.calls,
-                  tokens_in_window=tokens_in_window, steps=log.step)
+                  tokens_in_window=tokens_in_window, steps=log.step,
+                  step_s=step_s, gc_s=gc_s)

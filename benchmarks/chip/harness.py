@@ -1,20 +1,24 @@
 """One run of one cell: set-up, the measured window, the metrics, and
 the correctness check.
 
-Set-up makes the weights on the device from the seed, builds the
-engine (or the fleet) as the configuration's deployment says, and runs
+Set-up checks the configuration's model against ``ArchConfig`` and its
+deployment against the engine's options, makes the weights of its
+architecture module (``archs/<arch>.py``) on the device from the seed,
+builds the engine (or the fleet) as the deployment says, and runs
 a few short requests through it so that every tick program the traffic
 uses is compiled (from JAX's persistent cache after a cell's first run)
 before the window opens.  The window is ``driver.run_window``.  With
 ``trace`` a profile is taken over the middle ``TRACE_S`` seconds of the
 window and the per-layer metrics are read; without, the end-to-end
 ones.  Then the peak device memory is read, the program's state freed,
-and the sample of served requests compared with the float32 reference.
+and the sample of served requests compared with the module's float32
+reference.
 """
 from __future__ import annotations
 
 import dataclasses
 import gc
+import inspect
 import sys
 import tempfile
 import time
@@ -30,6 +34,13 @@ from benchmarks.chip import (cells, correct, driver, stats, traffic,
 TRACE_S = 10.0           # profiled part of a --trace 1 window, at most
 DRAIN_S = 90.0           # how long requests sent in the window may finish
 WARMUP = ((40, 24), (40, 4), (40, 12))  # (prompt, output) of warm-up requests
+# the traffic fixes every output length: no EOS stops one
+FIXED = {"eos_id": -1}
+# deployment keys the harness reads itself, not engine options
+PLACEMENT = ("chips", "replicas")
+# arguments the harness fills in from the cell, or that a JSON file
+# cannot state (a mesh, sharding rules, a decode function)
+FILLED = ("n_replicas", "model", "devices", "mesh", "rules", "decode_fn")
 
 
 class NoChip(RuntimeError):
@@ -63,32 +74,54 @@ def devices_for(chips: int, require_tpu: bool) -> list:
 
 
 def arch_config(m: dict):
+    """The ``ArchConfig`` of a configuration's ``model`` entry: every key
+    passed through; one that is not a field raises ``ValueError``."""
     from repro.configs.base import ArchConfig
-    return ArchConfig(**{k: m[k] for k in (
-        "name", "family", "n_layers", "d_model", "n_heads", "n_kv_heads",
-        "d_ff", "vocab", "head_dim", "rope_theta", "norm_eps", "act",
-        "dtype")})
+    fields = {f.name for f in dataclasses.fields(ArchConfig)}
+    unknown = sorted(set(m) - fields)
+    if unknown:
+        raise ValueError(f"model keys {unknown} are not ArchConfig fields")
+    return ArchConfig(**m)
 
 
-def build(cell: cells.Cell, params, devs: list):
-    """The system under test as the deployment says: a ServingEngine,
-    or a FleetSupervisor of one-chip replicas.  Returns (frontier,
-    engines)."""
+def _options(fn) -> set:
+    return {p.name for p in inspect.signature(fn).parameters.values()
+            if p.kind in (p.POSITIONAL_OR_KEYWORD, p.KEYWORD_ONLY)} \
+        - {"self", "params", "cfg"}
+
+
+def engine_options(dep: dict) -> dict:
+    """A configuration's ``deployment`` as keyword options: every key but
+    ``PLACEMENT`` goes to ``ServingEngine`` (with ``replicas`` over 1,
+    also a ``FleetSupervisor`` option), with ``FIXED`` added.  A key that
+    is neither, or that is ``FIXED`` or ``FILLED``, raises
+    ``ValueError``."""
     from repro.runtime.serve import ServingEngine
-    dep = cell.config["deployment"]
-    cfg = arch_config(cell.config["model"])
-    kw = dict(n_slots=dep["n_slots"], max_seq=dep["max_seq"],
-              paged=dep["paged"], block_size=dep["block_size"],
-              n_blocks=dep["n_blocks"],
-              chunked_prefill=dep["chunked_prefill"],
-              # the traffic fixes every output length: no EOS stops one
-              eos_id=-1)
-    if dep["replicas"] == 1:
-        engine = ServingEngine(params, cfg, **kw)
+    allowed = _options(ServingEngine.__init__)
+    if dep["replicas"] > 1:
+        from repro.runtime.supervisor import FleetSupervisor
+        allowed |= _options(FleetSupervisor.__init__)
+    allowed -= {*FIXED, *FILLED}
+    kw = {k: v for k, v in dep.items() if k not in PLACEMENT}
+    unknown = sorted(set(kw) - allowed)
+    if unknown:
+        raise ValueError(f"deployment keys {unknown} are not options of "
+                         f"the engine (or, with replicas, the fleet) that "
+                         f"the harness leaves open")
+    return {**kw, **FIXED}
+
+
+def build(params, cfg, options: dict, replicas: int, devs: list):
+    """The system under test as the deployment says: a ServingEngine,
+    or a FleetSupervisor of ``replicas`` one-chip replicas, given
+    ``options`` (``engine_options``).  Returns (frontier, engines)."""
+    from repro.runtime.serve import ServingEngine
+    if replicas == 1:
+        engine = ServingEngine(params, cfg, **options)
         return driver.Frontier(engine), [engine]
     from repro.runtime.supervisor import FleetSupervisor
-    fleet = FleetSupervisor(params, cfg, n_replicas=dep["replicas"],
-                            model=1, devices=devs, **kw)
+    fleet = FleetSupervisor(params, cfg, n_replicas=replicas, model=1,
+                            devices=devs, **options)
     return driver.FleetFrontier(fleet), list(fleet.engines)
 
 
@@ -141,11 +174,12 @@ def measure(name: str, seed: int, seconds: float, trace: bool, *,
     cell = cells.find_cell(name, root=root, here=here)
     if rate is not None:
         cell.mix["rate_per_s"] = rate
+    m, dep = cell.config["model"], cell.config["deployment"]
+    cfg, options = arch_config(m), engine_options(dep)
     devs = devices_for(cell.chips, require_tpu)
-    m = cell.config["model"]
     peak = cells.peaks(devs[0].device_kind) if require_tpu else None
-    params = weights.make(m, seed, devs[0])
-    frontier, engines = build(cell, params, devs)
+    params = weights.make(cell.arch, m, seed, devs[0])
+    frontier, engines = build(params, cfg, options, dep["replicas"], devs)
     warm_up(engines, m["vocab"])
     log = driver.TickLog()
     for e in engines:
@@ -200,6 +234,8 @@ def measure(name: str, seed: int, seconds: float, trace: bool, *,
     say(f"set-up {setup_s:.3f} s; window {window.close - window.open:.3f} "
         f"s, drained in {window.end - window.close:.3f} s; "
         f"{len(window.recs)} requests sent, {window.steps} steps")
+    say(host_steps(window) + "; compiles after the window opened: "
+        + ", ".join(f"{e.compiles} in {e.compile_s:.3f} s" for e in engines))
     tr = None
     if trace:
         t = time.perf_counter()
@@ -247,8 +283,9 @@ def run(name: str, seed: int, seconds: float, trace: bool, *, t0: float,
                                "idle_gaps": xplane.idle_by_host(tr)}
     t = time.perf_counter()
     checked = correct.sample(finished, seed)
-    ref_params = weights.make(data.m, seed, devs[0])
-    readings = correct.gaps(data.m, ref_params, checked, control=control)
+    ref_params = weights.make(cell.arch, data.m, seed, devs[0])
+    readings = correct.gaps(cell.arch, data.m, ref_params, checked,
+                            control=control)
     ok, checks = correct.decide(readings["program"], cell.limits)
     if control:
         c_ok, c_checks = correct.decide(readings["control"], cell.limits)
@@ -261,6 +298,23 @@ def run(name: str, seed: int, seconds: float, trace: bool, *, t0: float,
         f"requests in {time.perf_counter() - t:.3f} s; widest gap "
         f"{readings['program']['widest']}")
     return result
+
+
+def host_steps(window: driver.Window) -> str:
+    """One line on how long the window's steps took on the host, by the
+    tick family they called, and on garbage collection: a run that reads
+    slower than its seed did shows here whether every step was slower or
+    a few stood still."""
+    family = {c.step: c.family for c in window.ticks}
+    by: dict = {}
+    for i, s in enumerate(window.step_s):
+        by.setdefault(family.get(i, "none"), []).append(s)
+    parts = [f"{f} {len(v)} in {sum(v):.3f} s, median "
+             f"{1e3 * float(np.median(v)):.1f} ms, slowest {1e3 * max(v):.1f}"
+             for f, v in sorted(by.items())]
+    return (f"steps in the window: {'; '.join(parts) or 'none'}; garbage "
+            f"collection {sum(window.gc_s):.3f} s in {len(window.gc_s)} "
+            f"passes (longest {1e3 * max(window.gc_s, default=0):.1f} ms)")
 
 
 def say(line: str) -> None:

@@ -33,9 +33,10 @@ def read(run):
                 if m.start < span.end and m.end > span.start]
         if not runs:
             continue
-        contexts = sum(work.decode_contexts(p, k, n)
-                       for p, k, n in call.decoding)
-        flops, nbytes = work.decode_attention_work(run.m, contexts)
+        work_done = [work.decode_attention_work(run.cell.arch, run.m, *d)
+                     for d in call.decoding]
+        flops = sum(f for f, _ in work_done)
+        nbytes = sum(b for _, b in work_done)
         need_s += work.roofline_s(flops, nbytes, run.peak)
         kernel_s += sum(e.end - e.start for e in kernels
                         if any(m.start <= e.start and e.end <= m.end

@@ -80,15 +80,14 @@ def window_flops(run) -> float:
     token came in it.  Generated token j >= 1 of a request with a
     P-token prompt was computed from position P + j - 1 over P + j
     positions; token 0 is the prompt's."""
-    m = run.m
-    n_mm = 2.0 * work.matmul_params(m)
-    a1 = work.attention_flops(m, 1)
+    arch, m = run.cell.arch, run.m
+    n_mm = 2.0 * arch.matmul_params(m)
     total = 0.0
     for r in run.window.recs:
         n, p = r.n_in_window, r.prompt_len
         if n == 0:
             continue
-        total += work.prompt_flops(m, p)
+        total += work.prompt_flops(arch, m, p)
         gen = n - 1                     # tokens 1 .. n-1
-        total += n_mm * gen + a1 * (gen * p + gen * (gen + 1) / 2)
+        total += n_mm * gen + work.attention_sum(arch, m, p + 1, gen)
     return total

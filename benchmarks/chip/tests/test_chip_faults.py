@@ -1,14 +1,15 @@
 """A whole run of the harness on the CPU, at a tiny size, without the
 look for a chip: a sound run comes out correct, on one engine and on a
-fleet of two replicas, and a run whose engine alters the tokens where it
-produces them comes out not correct.  Also the float8 control at that
+fleet of two replicas, and with a block from an architecture module
+that no file of the harness names; a run whose engine alters the tokens
+where it produces them comes out not correct.  Also the float8 control at that
 size: it has to read above the limit that the program reads below."""
 from pathlib import Path
 
 import numpy as np
 import pytest
 
-from benchmarks.chip import harness
+from benchmarks.chip import cells, harness, weights
 
 TINY = Path(__file__).resolve().parent / "tiny"
 
@@ -40,6 +41,20 @@ def test_fleet_of_replicas_is_served_and_correct():
     r = run("tiny-x2.chat", 11)
     assert r["correct"] is True
     assert r["failed"] == 0 and r["attempted"] > 0
+
+
+def test_block_from_a_test_local_module_is_served_and_correct():
+    """``tiny-tied``'s configuration names ``"arch": "tied"``
+    (``tiny/archs/tied.py``) and states ``tie_embeddings``: the weights
+    hold no unembedding, so the engine serves only if the key reaches
+    it, and the dense reference would fail on them."""
+    cell = cells.find_cell("tiny-tied.closed", root=TINY, here=TINY)
+    assert Path(cell.arch.__file__) == TINY / "archs" / "tied.py"
+    assert "unembed" not in weights.make(cell.arch, cell.config["model"], 0)
+    r = run("tiny-tied.closed", 2**31 + 7)
+    assert r["correct"] is True
+    assert r["failed"] == 0 and r["attempted"] > 0
+    assert r["checks"]["tokens_checked"]["value"] >= 256
 
 
 def test_token_altered_where_produced_is_not_correct(monkeypatch):

@@ -2,6 +2,7 @@
 percentile is over all requests sent, a rate is over the whole window,
 TPOT is per request, latencies run from the due time, and a request not
 finished by the end of the drain counts as failed and as slow."""
+import gc
 import types
 
 import numpy as np
@@ -142,3 +143,34 @@ def test_closed_loop_client_sends_next_on_completion():
 
 def test_percentile_of_nothing_is_none():
     assert stats.percentile([], 90) is None
+
+
+def test_host_step_times_and_collections_inside_the_window():
+    # one slot, 0.1 s steps returning at 0.1 .. 0.8: a 0.45 s window
+    # times the four that return inside it; a collection run by a step
+    # inside the window is counted, one after its close is not
+    from benchmarks.chip.harness import host_steps
+
+    class Collecting(FakeEngine):
+        def step(self):
+            super().step()
+            gc.collect()
+
+    clock = Clock()
+    eng = Collecting(clock, slots=1)
+    w = driver.run_window(eng, [spec(0, 0.0, 8)], {"loop": "closed"},
+                          seconds=0.45, drain_s=100.0, make_request=request,
+                          log=driver.TickLog(), clock=clock,
+                          sleep=clock.sleep)
+    assert w.step_s == pytest.approx([0.1] * 4)
+    assert len(w.gc_s) == 4 and all(s >= 0 for s in w.gc_s)
+    line = host_steps(w)
+    assert "none 4 in 0.400 s, median 100.0 ms, slowest 100.0" in line
+    assert "in 4 passes" in line
+    assert on_gc_left() == 0
+
+
+def on_gc_left() -> int:
+    """Callbacks a window left registered with the collector."""
+    return sum(getattr(cb, "__name__", "") == "on_gc"
+               for cb in gc.callbacks)

@@ -33,8 +33,12 @@ def test_every_seed_gets_the_same_work(name):
     gaps = [np.sort(np.diff([0.0] + sorted(s.due_s for s in r)))
             for r in runs]
     assert all(g == pytest.approx(gaps[0]) for g in gaps)
-    # but not in the same order, nor with the same tokens
-    assert [s.max_new for s in runs[0]] != [s.max_new for s in runs[1]]
+    # an open loop in another order; a closed loop in its deal's order,
+    # the same for every seed; never with the same tokens
+    same_order = [[s.max_new for s in r] == [s.max_new for s in runs[0]]
+                  for r in runs[1:]]
+    assert all(same_order) if mix["loop"] == "closed" else \
+        not any(same_order)
     assert not np.array_equal(runs[0][0].prompt[:8], runs[1][0].prompt[:8])
 
 
@@ -82,6 +86,29 @@ def test_closed_loop_clients_share_the_pool():
             sorted(s.max_new for s in other[r:r + c])
         assert sorted(len(s.prompt) for s in specs[r:r + c]) == \
             sorted(len(s.prompt) for s in specs[:c])
+
+
+def test_closed_loop_order_comes_from_the_deal():
+    mix = cells.load_mix("long-output")
+
+    def order(m, seed):
+        return [(s.rid, s.client, len(s.prompt), s.max_new)
+                for s in traffic.make_requests(m, seed=seed, seconds=51,
+                                               vocab=1000)]
+
+    first = order(mix, SEEDS[0])
+    assert all(order(mix, s) == first for s in SEEDS[1:])
+    # another deal: the same lengths in another order
+    other = order({**mix, "deal": mix["deal"] + 1}, SEEDS[0])
+    assert other != first
+    assert sorted(o[3] for o in other) == sorted(o[3] for o in first)
+
+
+def test_closed_mix_without_a_deal_raises():
+    mix = {k: v for k, v in cells.load_mix("long-output").items()
+           if k != "deal"}
+    with pytest.raises(ValueError, match="deal"):
+        traffic.make_requests(mix, seed=1, seconds=51, vocab=1000)
 
 
 def test_base_mix_is_merged(tmp_path):

@@ -10,10 +10,13 @@ Every seed gets the same work.  The lengths are the distribution's
 quantiles at evenly spaced points and the Poisson gaps the
 exponential's, so the multiset of prompt lengths, of output lengths and
 of gaps is the same for every seed (for a closed loop, in every round
-of one request per client); the seed only orders and pairs them and
-draws the token ids.  Two seeds then differ in the order of the
-work, not in its amount, which keeps the spread between seeds close to
-the spread between two runs of one seed.
+of one request per client).  In an open loop the seed orders and pairs
+them; two seeds then differ in the order of the work, not in its
+amount.  A closed loop's window holds only a few rounds, and there the
+order is the work: which requests run side by side, and how many
+prompts are admitted in the window.  So a closed mix states ``deal``,
+the number its order and pairing of lengths are drawn from, the same
+for every seed.  The seed always draws the token ids.
 """
 from __future__ import annotations
 
@@ -74,13 +77,17 @@ def make_requests(mix: dict, *, seed: int, seconds: float,
     work is fixed round by round: round r gives each of the ``clients``
     one request, and every round holds the same lengths (the
     distributions' quantiles at ``clients`` points), dealt to the
-    clients in an order drawn from the seed."""
-    order = seed_rng(seed, 0)
+    clients in an order drawn from the mix's ``deal``, not the seed."""
     if mix["loop"] == "open":
+        order = seed_rng(seed, 0)
         n = n_open_requests(mix, seconds)
         prompt_len = order.permutation(quantile_lengths(mix["prompt"], n))
         output_len = order.permutation(quantile_lengths(mix["output"], n))
     elif mix["loop"] == "closed":
+        if "deal" not in mix:
+            raise ValueError("a closed-loop mix states its 'deal', the "
+                             "number its order of lengths is drawn from")
+        order = seed_rng(int(mix["deal"]), 0)
         c = int(mix["clients"])
         n = int(mix["requests"]) // c * c
         p_round = quantile_lengths(mix["prompt"], c)

@@ -6,43 +6,34 @@ not the padding, not the block table's length, not the slots left idle.
 A program that stops doing work the traffic does not need therefore
 raises its roofline and utilization shares; it cannot change the
 yardstick.
+
+The counts of one token come from the configuration's architecture
+module ``arch`` (``archs/<arch>.py``): ``matmul_params``,
+``attention_flops`` and ``kv_bytes_per_position``.  The sums over a
+prompt or over decode steps are built here from them, one context at a
+time, so that a block whose attention does not grow with the context
+(a window, a recurrent state) counts its FLOPs right.  The bytes of
+decode attention assume that every cached position is read.
 """
 from __future__ import annotations
 
-KV_BYTES = 2                        # bfloat16 cache entries
+
+def attention_sum(arch, m: dict, first: int, n: int) -> float:
+    """Attention FLOPs of ``n`` queries over ``first``, ``first + 1``,
+    ... ``first + n - 1`` positions."""
+    return sum(arch.attention_flops(m, c) for c in range(first, first + n))
 
 
-def matmul_params(m: dict) -> int:
-    """Weights multiplied per token: the projections of every layer and
-    the unembedding over the vocabulary (the embedding is a gather)."""
-    d, h, hkv, dh, f = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                        m["head_dim"], m["d_ff"])
-    mlp = (3 if m["act"] == "silu" else 2) * d * f
-    attn = 2 * d * h * dh + 2 * d * hkv * dh
-    return m["n_layers"] * (attn + mlp) + d * m["vocab"]
-
-
-def attention_flops(m: dict, context: int) -> float:
-    """Scores and weighted sum of one query over ``context`` keys, in
-    every layer."""
-    return 4.0 * m["n_layers"] * m["n_heads"] * m["head_dim"] * context
-
-
-def token_flops(m: dict, context: int) -> float:
+def token_flops(arch, m: dict, context: int) -> float:
     """One token's forward, attending over ``context`` positions (its
     own included)."""
-    return 2.0 * matmul_params(m) + attention_flops(m, context)
+    return 2.0 * arch.matmul_params(m) + arch.attention_flops(m, context)
 
 
-def prompt_flops(m: dict, prompt_len: int) -> float:
+def prompt_flops(arch, m: dict, prompt_len: int) -> float:
     """A whole prompt, causally: position p attends over p + 1."""
-    return (2.0 * matmul_params(m) * prompt_len
-            + attention_flops(m, 1) * prompt_len * (prompt_len + 1) / 2)
-
-
-def kv_bytes_per_position(m: dict) -> int:
-    """Keys and values of one position, over all layers."""
-    return m["n_layers"] * 2 * m["n_kv_heads"] * m["head_dim"] * KV_BYTES
+    return (2.0 * arch.matmul_params(m) * prompt_len
+            + attention_sum(arch, m, 1, prompt_len))
 
 
 def decode_contexts(prompt_len: int, out_before: int, steps: int) -> int:
@@ -55,11 +46,14 @@ def decode_contexts(prompt_len: int, out_before: int, steps: int) -> int:
     return steps * c0 + steps * (steps - 1) // 2
 
 
-def decode_attention_work(m: dict, contexts: int) -> tuple:
-    """``(flops, bytes)`` the decode attention of ``contexts`` summed
-    attended positions needs: every cached key and value read once."""
-    return (attention_flops(m, 1) * contexts,
-            kv_bytes_per_position(m) * contexts)
+def decode_attention_work(arch, m: dict, prompt_len: int, out_before: int,
+                          steps: int) -> tuple:
+    """``(flops, bytes)`` the decode attention of those ``steps`` steps
+    (see ``decode_contexts``) needs: every cached key and value read
+    once."""
+    return (attention_sum(arch, m, prompt_len + out_before, steps),
+            arch.kv_bytes_per_position(m)
+            * decode_contexts(prompt_len, out_before, steps))
 
 
 def roofline_s(flops: float, nbytes: float, peak: dict) -> float:
