@@ -21,6 +21,7 @@ ARCH_IDS = [
     "pixtral-12b",
     "zamba2-1.2b",
     "mamba2-780m",
+    "granite-4.0-h-micro",
     # the paper's own workload (EMPA Y86 sumup) is a simulator config, not
     # an LM; see configs/empa_y86.py
 ]
@@ -29,7 +30,7 @@ ARCH_IDS = [
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                    # dense | moe | ssm | hybrid | encdec | vlm
+    family: str    # dense | moe | ssm | hybrid | interleaved | encdec | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -48,6 +49,10 @@ class ArchConfig:
     ssm_expand: int = 2
     ssm_conv: int = 4
     ssm_ngroups: int = 1
+    ssm_chunk: int = 64            # SSD chunk length (interleaved family)
+    # --- interleaved: a Mamba2 or attention mixer per layer, named by
+    #     `layer_types` ("mamba" | "attention"), each followed by an MLP ---
+    layer_types: tuple = ()
     # --- hybrid (zamba2): one shared attention+MLP block applied
     #     every `shared_attn_every` SSM blocks ---
     shared_attn_every: int = 0
@@ -62,12 +67,20 @@ class ArchConfig:
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
     act: str = "silu"
-    pos_embed: str = "rope"          # rope | learned
+    pos_embed: str = "rope"          # rope | learned | none
     max_position: int = 1 << 20
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
     # sub-quadratic attention available? (drives long_500k applicability)
     subquadratic: bool = False
+    # --- muP multipliers (interleaved family): token embeddings times
+    #     `embedding_multiplier`; attention scores times
+    #     `attention_multiplier` (None: 1/sqrt(head_dim)); each residual
+    #     branch times `residual_multiplier`; logits over `logits_scaling`
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -75,6 +88,24 @@ class ArchConfig:
         if self.n_heads and self.n_kv_heads:
             assert self.n_heads % self.n_kv_heads == 0, \
                 f"{self.name}: GQA requires n_heads % n_kv_heads == 0"
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.pos_embed not in ("rope", "learned", "none"):
+            raise ValueError(f"{self.name}: pos_embed {self.pos_embed!r}")
+        if self.family == "interleaved":
+            kinds = set(self.layer_types)
+            if len(self.layer_types) != self.n_layers \
+                    or not kinds <= {"mamba", "attention"}:
+                raise ValueError(
+                    f"{self.name}: layer_types must name 'mamba' or "
+                    f"'attention' for each of the {self.n_layers} layers")
+        else:
+            mup = (self.embedding_multiplier, self.attention_multiplier,
+                   self.residual_multiplier, self.logits_scaling)
+            if self.layer_types or mup != (1.0, None, 1.0, 1.0):
+                raise ValueError(
+                    f"{self.name}: layer_types and the muP multipliers "
+                    f"are served for the interleaved family only, not "
+                    f"{self.family!r}")
 
     # ---- derived sizes -----------------------------------------------
     @property
@@ -96,8 +127,25 @@ class ArchConfig:
         return self.d_inner // self.ssm_headdim
 
     @property
+    def layer_period(self) -> int:
+        """Layers in one repeat of ``layer_types`` (interleaved): the
+        stack scans whole periods, so each layer's kind is static."""
+        t, n = self.layer_types, self.n_layers
+        return next(p for p in range(1, n + 1)
+                    if n % p == 0 and t == t[:p] * (n // p))
+
+    @property
+    def n_attn_layers(self) -> int:
+        return self.layer_types.count("attention")
+
+    @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer keeps a recurrent state that absorbs every token."""
+        return self.family in ("ssm", "hybrid", "interleaved")
 
     @property
     def is_attention_free(self) -> bool:
@@ -186,6 +234,9 @@ def reduced(cfg: ArchConfig, **overrides) -> ArchConfig:
         small.update(ssm_state=16, ssm_headdim=32)
     if cfg.shared_attn_every:
         small.update(shared_attn_every=2, n_layers=4)
+    if cfg.layer_types:
+        small.update(layer_types=("mamba", "attention", "mamba"),
+                     n_layers=3)
     if cfg.enc_layers:
         small.update(enc_layers=2, dec_layers=2)
     if cfg.frontend:

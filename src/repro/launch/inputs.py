@@ -66,13 +66,13 @@ def cache_axes(cfg: ArchConfig, paged: bool = False) -> dict:
         ("layers", "cache_batch", None, "cache_kv_heads", "cache_head_dim")
     ax = {"pos": ("cache_batch",)}
     fam = cfg.family
-    if fam in ("dense", "moe", "vlm", "encdec"):
+    if fam in ("dense", "moe", "vlm", "encdec", "interleaved"):
         ax.update(k=kv, v=kv)
         if paged:
             ax["block_tables"] = ("cache_batch", None)
         if fam == "encdec":
             ax.update(xk=kv, xv=kv)
-    if fam in ("ssm", "hybrid"):
+    if fam in ("ssm", "hybrid", "interleaved"):
         ax.update(conv=("layers", "cache_batch", None, "conv_dim"),
                   state=("layers", "cache_batch", "ssm_heads", None,
                          "ssm_state"))

@@ -9,6 +9,9 @@ functions (`loss_fn`, `prefill`, `decode_step`) covers:
 * zamba2-style hybrids (SSD stack + ONE shared attention block applied
   every k layers — the shared block is a rented core: one weight set,
   many QTs),
+* interleaved stacks (a Mamba2 or a GQA mixer per layer, as
+  ``cfg.layer_types`` names them, each followed by an MLP; muP
+  multipliers — granite-4.0-h),
 * whisper-style encoder-decoder (stub audio frontend per assignment).
 
 Layers are stacked (leading L axis) and scanned — the FOR-mode discipline:
@@ -155,6 +158,12 @@ def param_defs(cfg: ArchConfig) -> list[ParamDef]:
             defs += _mlp_defs(("layers",), cfg, L)
     elif fam == "ssm":
         defs += _mamba_defs(("layers",), cfg, cfg.n_layers)
+    elif fam == "interleaved":
+        L, na = cfg.n_layers, cfg.n_attn_layers
+        defs += [_norm(("mlp",), cfg, L, "ln")] + _mlp_defs(("mlp",), cfg, L)
+        defs += [_norm(("attn",), cfg, na, "ln")]
+        defs += _attn_defs(("attn",), cfg, na)
+        defs += _mamba_defs(("mamba",), cfg, L - na)
     elif fam == "hybrid":
         defs += _mamba_defs(("layers",), cfg, cfg.n_layers)
         defs += [_norm(("shared",), cfg, None, "ln1"),
@@ -209,7 +218,7 @@ def _attention(x_q, x_kv, p, cfg: ArchConfig, q_pos, kv_pos, *, causal,
     if cfg.pos_embed == "rope":
         q = layers.apply_rope(q, q_pos, cfg.rope_theta)
         k = layers.apply_rope(k, kv_pos, cfg.rope_theta)
-    q = _sh(q, ("batch", None, "heads_act", None))
+    q = _sh(_scale_q(q, cfg), ("batch", None, "heads_act", None))
     if cache_kv is not None:
         # decode: attend over the cache (k/v already written by caller)
         ck, cv = cache_kv
@@ -220,6 +229,26 @@ def _attention(x_q, x_kv, p, cfg: ArchConfig, q_pos, kv_pos, *, causal,
         o = attn_lib.full_attention(q, k, v, causal=causal)
     out = jnp.einsum("bshk,hkd->bsd", o, p[f"w{sfx}o"])
     return out, (k, v)
+
+
+def _scale_q(q, cfg: ArchConfig):
+    """muP attention scale: the kernels scale scores by 1/sqrt(head_dim),
+    so q is pre-scaled to make the product ``attention_multiplier``."""
+    if cfg.attention_multiplier is None:
+        return q
+    return q * (cfg.attention_multiplier * cfg.head_dim ** 0.5)
+
+
+def _residual(h, cfg: ArchConfig):
+    """muP residual branch scale."""
+    return h if cfg.residual_multiplier == 1.0 else \
+        h * cfg.residual_multiplier
+
+
+def _embed_tokens(params, tokens, cfg: ArchConfig):
+    x = layers.embed(params["embed"]["tok"], tokens)
+    return x if cfg.embedding_multiplier == 1.0 else \
+        x * cfg.embedding_multiplier
 
 
 def _ffn(x, p, cfg: ArchConfig):
@@ -267,6 +296,73 @@ def _mamba_layer(x, lp, cfg: ArchConfig):
     return x + h
 
 
+MAMBA_LEAVES, ATTN_LEAVES = ("conv", "state"), ("k", "v")
+
+
+def slot_state_leaves(cfg: ArchConfig) -> tuple:
+    """Cache leaves of per-slot recurrent state (batch axis 1) that a
+    paged cache keeps beside its pages."""
+    return MAMBA_LEAVES if cfg.family == "interleaved" else ()
+
+
+def _interleaved_stack(params, x, cfg: ArchConfig, mamba_fn, attn_fn,
+                       state: dict, remat=False, remat_policy=None):
+    """The interleaved family's layers.  Each layer adds its mixer, of
+    the kind ``cfg.layer_types`` names, over its pre-norm, then its MLP
+    over its own pre-norm, both through ``_residual``.
+
+    One ``lax.scan`` over whole periods of the pattern
+    (``cfg.layer_period``) with each period's layers unrolled, so a
+    layer's kind is static and each mixer reads only its own weights.
+    ``state`` holds cache leaves stacked over the layers of their kind
+    (``MAMBA_LEAVES`` over the Mamba layers, ``ATTN_LEAVES`` over the
+    attention layers; ``{}`` without a cache).  ``mamba_fn`` and
+    ``attn_fn`` take ``(normed x, layer weights, the layer's leaves)``
+    and return ``(out, new leaves)``.  Returns ``(x, new state)``.
+
+    Each layer indexes its weights and leaves out of the whole stacks,
+    and writes its leaves back into the carried stacks in place: as
+    scan inputs and outputs, XLA copies out each period's weights and
+    builds a second stack of the leaves (at granite-4.0-h-micro's
+    decode chunk, 9.3 GB of temporaries against 2.4 GB)."""
+    period = cfg.layer_period
+    kinds = cfg.layer_types[:period]
+    count = {k: kinds.count(k) for k in ("mamba", "attention")}
+    group = {"mamba": ("mamba", mamba_fn, MAMBA_LEAVES),
+             "attention": ("attn", attn_fn, ATTN_LEAVES)}
+
+    def take(tree, i):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            tree)
+
+    def body(carry, p):
+        x, st = carry
+        at = {"mamba": 0, "attention": 0}
+        for j, kind in enumerate(kinds):
+            name, fn, leaf_names = group[kind]
+            i = p * count[kind] + at[kind]
+            at[kind] += 1
+            lp = take(params[name], i)
+            names = [k for k in leaf_names if k in st]
+            h, leaves = fn(layers.rms_norm(x, lp["ln"], cfg.norm_eps), lp,
+                           tuple(take(st[k], i) for k in names))
+            st = dict(st, **{
+                k: jax.lax.dynamic_update_index_in_dim(st[k], leaf, i, 0)
+                for k, leaf in zip(names, leaves)})
+            x = x + _residual(h, cfg)
+            mp = take(params["mlp"], p * period + j)
+            y = layers.mlp(layers.rms_norm(x, mp["ln"], cfg.norm_eps), mp,
+                           cfg.act)
+            x = x + _residual(y, cfg)
+        return (x, st), None
+
+    (x, state), _ = jax.lax.scan(_maybe_remat(body, remat, remat_policy),
+                                 (x, state),
+                                 jnp.arange(cfg.n_layers // period))
+    return x, state
+
+
 def _shared_attn_block(x, sp, cfg: ArchConfig, positions):
     h, kv = _attention(layers.rms_norm(x, sp["ln1"], cfg.norm_eps),
                        layers.rms_norm(x, sp["ln1"], cfg.norm_eps),
@@ -283,7 +379,7 @@ def _shared_attn_block(x, sp, cfg: ArchConfig, positions):
 def _embed_inputs(params, batch, cfg: ArchConfig):
     """Token (+frontend) embedding.  Returns (x (B,S,d), positions (S,))."""
     tok = batch["tokens"]
-    x = layers.embed(params["embed"]["tok"], tok)
+    x = _embed_tokens(params, tok, cfg)
     if cfg.frontend == "vision":
         vis = jnp.einsum("bnf,fd->bnd",
                          batch["vision_embeds"].astype(x.dtype),
@@ -319,6 +415,19 @@ def _run_stack(params, x, cfg: ArchConfig, positions, *, remat,
             return _mamba_layer(carry, lp, cfg), jnp.float32(0.0)
         f = _maybe_remat(body, remat, remat_policy)
         x, _ = jax.lax.scan(f, x, params["layers"])
+        return x, jnp.float32(0.0)
+
+    if fam == "interleaved":
+        ssd = functools.partial(ssm.ssd_chunked, chunk=cfg.ssm_chunk)
+
+        def mamba(h, lp, _):
+            return ssm.mamba2_block(h, lp, cfg, ssd_fn=ssd)[0], ()
+
+        def attend(h, lp, _):
+            return _attention(h, h, lp, cfg, positions, positions,
+                              causal=True)[0], ()
+        x, _ = _interleaved_stack(params, x, cfg, mamba, attend, {},
+                                  remat=remat, remat_policy=remat_policy)
         return x, jnp.float32(0.0)
 
     if fam == "hybrid":
@@ -406,8 +515,10 @@ def _unembed_table(params, cfg: ArchConfig):
 
 
 def _logits(x, params, cfg: ArchConfig):
-    return layers.unembed_logits(x, _unembed_table(params, cfg),
-                                 true_vocab=cfg.vocab)
+    logits = layers.unembed_logits(x, _unembed_table(params, cfg),
+                                   true_vocab=cfg.vocab)
+    return logits if cfg.logits_scaling == 1.0 else \
+        logits / cfg.logits_scaling
 
 
 def chunked_loss(x, table, labels, chunk: int = LOSS_CHUNK,
@@ -440,6 +551,8 @@ def loss_fn(params, batch, cfg: ArchConfig, *, remat=True, remat_policy=None):
     labels = batch["labels"]
     if cfg.frontend == "vision":
         x = x[:, -labels.shape[1]:]   # loss over the text tail only
+    if cfg.logits_scaling != 1.0:
+        x = x / cfg.logits_scaling    # logits are linear in x
     ce = chunked_loss(x, _unembed_table(params, cfg), labels,
                       true_vocab=cfg.vocab)
     loss = ce + AUX_LOSS_WEIGHT * aux
@@ -460,8 +573,10 @@ class PagedLayout:
     `block_size` positions, addressed per slot through a block table
     (runtime/paging.py owns the rent/release discipline over them).
 
-    Only causal attention-cache families (dense/moe/vlm) page; recurrent
-    state (ssm/hybrid) is O(1) per slot and has nothing to page.
+    Causal attention caches page (dense/moe/vlm, and the attention
+    layers of the interleaved family); recurrent state is O(1) per slot
+    and has nothing to page: the interleaved family keeps it per slot
+    beside the pages.
     """
 
     block_size: int
@@ -471,7 +586,10 @@ class PagedLayout:
         return -(-max_seq // self.block_size)
 
 
-PAGED_FAMILIES = ("dense", "moe", "vlm")
+# families whose attention caches page and whose prompts go in as
+# fragments (prefill_chunk); the interleaved family's Mamba layers carry
+# their state across fragments per slot
+PAGED_FAMILIES = ("dense", "moe", "vlm", "interleaved")
 
 
 def init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype=jnp.bfloat16,
@@ -493,11 +611,11 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype=jnp.bfloat16,
             (n_layers, layout.n_blocks, layout.block_size, hkv, dh)
         return {name: mk(shape, dtype) for name in names}
 
-    def recurrent() -> dict:
+    def recurrent(n_layers: int) -> dict:
         return {
-            "conv": mk((cfg.n_layers, batch, cfg.ssm_conv - 1,
+            "conv": mk((n_layers, batch, cfg.ssm_conv - 1,
                         ssm.conv_dim(cfg)), dtype),
-            "state": mk((cfg.n_layers, batch, cfg.ssm_nheads,
+            "state": mk((n_layers, batch, cfg.ssm_nheads,
                          cfg.ssm_headdim, cfg.ssm_state), jnp.float32),
         }
 
@@ -507,16 +625,19 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype=jnp.bfloat16,
             f"paged KV cache supports {PAGED_FAMILIES}, not {fam!r}: "
             "recurrent/cross-attention state is not paged")
     cache = {"pos": mk((batch,), jnp.int32)}
-    if fam in ("dense", "moe", "vlm"):
-        cache.update(kv(cfg.n_layers, "k", "v"))
+    if fam in PAGED_FAMILIES:
+        n_attn = cfg.n_attn_layers if fam == "interleaved" else cfg.n_layers
+        cache.update(kv(n_attn, "k", "v"))
+        if fam == "interleaved":
+            cache.update(recurrent(cfg.n_layers - n_attn))
         if layout is not None:
             nb = layout.max_blocks(max_seq)
             cache["block_tables"] = mk((batch, nb), jnp.int32) \
                 if abstract_only else jnp.full((batch, nb), -1, jnp.int32)
     elif fam == "ssm":
-        cache.update(recurrent())
+        cache.update(recurrent(cfg.n_layers))
     elif fam == "hybrid":
-        cache.update(recurrent())
+        cache.update(recurrent(cfg.n_layers))
         cache.update(kv(cfg.n_layers // cfg.shared_attn_every, "k", "v"))
     elif fam == "encdec":
         cache.update(kv(cfg.dec_layers, "k", "v", "xk", "xv"))
@@ -532,9 +653,12 @@ def _prefill_paged(params, batch, cfg: ArchConfig, max_seq: int,
     instead scatters into *rented* blocks and grows chains on demand
     (runtime/paging.py); this path is the standalone cache API (plans,
     parity tests, single-shot generation)."""
-    if cfg.family not in PAGED_FAMILIES:    # fail before the inner prefill
+    if cfg.family not in PAGED_FAMILIES or cfg.recurrent:
+        # fail before the inner prefill; recurrent state goes in through
+        # prefill_chunk
         raise ValueError(
-            f"paged KV cache supports {PAGED_FAMILIES}, not {cfg.family!r}")
+            f"paged KV cache supports {PAGED_FAMILIES} without recurrent "
+            f"layers in one static prefill, not {cfg.family!r}")
     bsz = batch["tokens"].shape[0]
     bs = layout.block_size
     span = batch["tokens"].shape[1]
@@ -583,6 +707,13 @@ def prefill(params, batch, cfg: ArchConfig, max_seq: int, lengths=None,
     # cache precision follows the parameters (bf16 in production, f32 in
     # the CPU consistency tests)
     cache = init_cache(cfg, bsz, max_seq, dtype=params["embed"]["tok"].dtype)
+    if fam == "interleaved":
+        # one fragment per row from position 0: right-padding is exact,
+        # padded columns leave every state as it was
+        tokens = batch["tokens"]
+        if lengths is None:
+            lengths = jnp.full((bsz,), tokens.shape[1], jnp.int32)
+        return prefill_chunk(params, tokens, lengths, cache, cfg)
 
     if fam in ("dense", "moe", "vlm"):
         x, positions = _embed_inputs(params, batch, cfg)
@@ -714,6 +845,7 @@ def _decode_attn_layer(x1, lp, cfg, k_l, v_l, pos, sfx=""):
     if cfg.pos_embed == "rope":
         q = layers.apply_rope(q, q_pos, cfg.rope_theta)
         k = layers.apply_rope(k, q_pos, cfg.rope_theta)
+    q = _scale_q(q, cfg)
     # write the new K/V at each row's position
     bidx = jnp.arange(bsz)
     k_l = k_l.at[bidx, pos].set(k[:, 0].astype(k_l.dtype))
@@ -738,6 +870,7 @@ def _decode_attn_layer_paged(x1, lp, cfg, k_l, v_l, pos, blk, off, tables,
     if cfg.pos_embed == "rope":
         q = layers.apply_rope(q, q_pos, cfg.rope_theta)
         k = layers.apply_rope(k, q_pos, cfg.rope_theta)
+    q = _scale_q(q, cfg)
     wblk = jnp.where(blk >= 0, blk, n_pages)   # out of range -> dropped
     k_l = k_l.at[wblk, off].set(k[:, 0].astype(k_l.dtype), mode="drop")
     v_l = v_l.at[wblk, off].set(v[:, 0].astype(v_l.dtype), mode="drop")
@@ -746,21 +879,24 @@ def _decode_attn_layer_paged(x1, lp, cfg, k_l, v_l, pos, blk, off, tables,
     return out, k_l, v_l
 
 
-def decode_step(params, token, cache, cfg: ArchConfig):
+def decode_step(params, token, cache, cfg: ArchConfig, advance=None):
     """One decode step.  token: (B,) int32.  Returns (logits (B,V), cache).
 
     Accepts either cache layout from :func:`init_cache`: the presence of
-    ``block_tables`` selects the paged write/attend path.
+    ``block_tables`` selects the paged write/attend path.  ``advance``
+    (B,) bool, optional (interleaved family): the rows whose recurrent
+    state the step advances; the others keep theirs.
     """
     bsz = token.shape[0]
     pos = cache["pos"]
-    x = layers.embed(params["embed"]["tok"], token)[:, None]   # (B,1,d)
+    x = _embed_tokens(params, token, cfg)[:, None]   # (B,1,d)
     if cfg.pos_embed == "learned":
         x = x + layers.learned_pos_embed(params["embed"]["pos"],
                                          pos[:, None])
     fam = cfg.family
+    paged = fam in PAGED_FAMILIES and "block_tables" in cache
 
-    if fam in PAGED_FAMILIES and "block_tables" in cache:
+    if paged:
         tables = cache["block_tables"]
         blk_size = cache["k"].shape[2]
         nb = tables.shape[1]
@@ -772,6 +908,25 @@ def decode_step(params, token, cache, cfg: ArchConfig):
         blk = jnp.where(blk_idx < nb, blk, -1)
         off = pos % blk_size
 
+    if fam == "interleaved":
+        def mamba(h, lp, leaves):
+            y, conv_l, state_l = ssm.mamba2_decode(h[:, 0], lp, cfg,
+                                                   *leaves, advance)
+            return y[:, None], (conv_l, state_l)
+
+        def attend(h, lp, leaves):
+            if paged:
+                out, k_l, v_l = _decode_attn_layer_paged(
+                    h, lp, cfg, *leaves, pos, blk, off, tables)
+            else:
+                out, k_l, v_l = _decode_attn_layer(h, lp, cfg, *leaves, pos)
+            return out, (k_l, v_l)
+        x, new = _interleaved_stack(
+            params, x, cfg, mamba, attend,
+            {k: cache[k] for k in MAMBA_LEAVES + ATTN_LEAVES})
+        cache = dict(cache, **new)
+
+    elif paged:
         def body(carry, inp):
             lp, k_l, v_l = inp
             h_in = layers.rms_norm(carry, lp["ln1"], cfg.norm_eps)
@@ -895,9 +1050,13 @@ def prefill_chunk(params, tokens, lengths, cache, cfg: ArchConfig,
       it are *not* stored (they live in shared prefix blocks an earlier
       chain already wrote); attention still reads them from the cache.
     * Works on both cache layouts from :func:`init_cache` (contiguous
-      and paged).  Causal-attention families only (dense/moe): recurrent
-      state absorbs tokens sequentially and a frontend's prepended
-      embeddings are not in token space — both keep the monolithic path.
+      and paged).  ``PAGED_FAMILIES`` without a frontend only: a
+      frontend's prepended embeddings are not in token space.  In the
+      interleaved family each row's Mamba layers advance their conv
+      window and SSM state over that row's ``length`` tokens only
+      (``ssm.mamba2_chunk``), so any fragment width is exact; they
+      cannot skip a prefix, so ``skip_until`` fences only the K/V
+      writes of the attention layers.
 
     Returns ``(logits (B, V) at each row's last valid column, advanced
     cache)``.  With ``all_logits=True`` the logits are returned for
@@ -920,7 +1079,7 @@ def prefill_chunk(params, tokens, lengths, cache, cfg: ArchConfig,
     if skip_until is not None:
         valid = valid & (q_pos >= jnp.asarray(skip_until,
                                               jnp.int32)[:, None])
-    x = layers.embed(params["embed"]["tok"], tokens)
+    x = _embed_tokens(params, tokens, cfg)
     if cfg.pos_embed == "learned":
         x = x + layers.learned_pos_embed(params["embed"]["pos"], q_pos)
 
@@ -945,15 +1104,14 @@ def prefill_chunk(params, tokens, lengths, cache, cfg: ArchConfig,
         bidx = jnp.arange(bsz)[:, None]
         span_idx = attn_lib.attended_span(q_pos, smax)
 
-    def body(carry, inp):
-        lp, k_l, v_l = inp
-        h_in = layers.rms_norm(carry, lp["ln1"], cfg.norm_eps)
+    def attend(h_in, lp, k_l, v_l):
         q = jnp.einsum("bsd,dhk->bshk", h_in, lp["wq"])
         k = jnp.einsum("bsd,dhk->bshk", h_in, lp["wk"])
         v = jnp.einsum("bsd,dhk->bshk", h_in, lp["wv"])
         if cfg.pos_embed == "rope":
             q = layers.apply_rope(q, q_pos, cfg.rope_theta)
             k = layers.apply_rope(k, q_pos, cfg.rope_theta)
+        q = _scale_q(q, cfg)
         # write-then-attend: the fragment's own K/V are in the cache
         # before the position-offset causal mask reads them
         if paged:
@@ -966,20 +1124,40 @@ def prefill_chunk(params, tokens, lengths, cache, cfg: ArchConfig,
             v_l = v_l.at[bidx, wpos].set(v.astype(v_l.dtype), mode="drop")
             o = attn_lib.chunk_attention(q, k_l, v_l, q_pos,
                                          span_idx=span_idx)
-        h = jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+        return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), k_l, v_l
+
+    def body(carry, inp):
+        lp, k_l, v_l = inp
+        h_in = layers.rms_norm(carry, lp["ln1"], cfg.norm_eps)
+        h, k_l, v_l = attend(h_in, lp, k_l, v_l)
         y = carry + h
         f, _ = _ffn(layers.rms_norm(y, lp["ln2"], cfg.norm_eps), lp, cfg)
         return y + f, (k_l, v_l)
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"],
-                                         cache["v"]))
+    if cfg.family == "interleaved":
+        def mamba(h, lp, leaves):
+            y, conv_l, state_l = ssm.mamba2_chunk(h, lp, cfg, *leaves,
+                                                  lengths)
+            return y, (conv_l, state_l)
+
+        def attend_layer(h, lp, leaves):
+            out, k_l, v_l = attend(h, lp, *leaves)
+            return out, (k_l, v_l)
+        x, new = _interleaved_stack(
+            params, x, cfg, mamba, attend_layer,
+            {k: cache[k] for k in MAMBA_LEAVES + ATTN_LEAVES})
+        cache = dict(cache, **new)
+    else:
+        x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"],
+                                             cache["v"]))
+        cache = dict(cache, k=ks, v=vs)
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     if all_logits:
         logits = _logits(x, params, cfg)                   # (B, C, V)
     else:
         x_last = x[jnp.arange(bsz), jnp.clip(lengths - 1, 0, span - 1)]
         logits = _logits(x_last, params, cfg)
-    cache = dict(cache, k=ks, v=vs, pos=pos0 + lengths)
+    cache = dict(cache, pos=pos0 + lengths)
     return logits, cache
 
 

@@ -26,20 +26,39 @@ def _to_heads(bc, nheads: int):
 
 
 def ssd_chunked(x, dt, a_log, b_mat, c_mat, d_skip, dt_bias,
-                chunk: int = 64, init_state=None):
+                chunk: int = 64, init_state=None, valid=None):
     """Chunked SSD scan.
 
     x: (B, S, H, P); dt: (B, S, H); a_log: (H,); b_mat/c_mat: (B, S, G, N);
     d_skip: (H,); dt_bias: (H,).  Returns (y (B,S,H,P), state (B,H,P,N)).
+
+    ``valid`` (B, S) bool, optional: the positions that advance the
+    state.  At the others the step is taken as dt = 0 with no input, so
+    the state passes through them exactly (a row's padding, or a row
+    with nothing to consume); their outputs are garbage.  A length that
+    is not a multiple of ``chunk`` is padded with such positions.
     """
     bsz, s, h, p = x.shape
     n = b_mat.shape[-1]
     chunk = min(chunk, s)
-    assert s % chunk == 0, (s, chunk)
+    if s % chunk:
+        pad = -s % chunk
+        if valid is None:
+            valid = jnp.ones((bsz, s), bool)
+
+        def grow(a):
+            return jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        y, state = ssd_chunked(grow(x), grow(dt), a_log, grow(b_mat),
+                               grow(c_mat), d_skip, dt_bias, chunk=chunk,
+                               init_state=init_state, valid=grow(valid))
+        return y[:, :s], state
     nc = s // chunk
 
     f32 = jnp.float32
     dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))        # (B,S,H)
+    if valid is not None:
+        dt = jnp.where(valid[..., None], dt, 0.0)
+        x = jnp.where(valid[..., None, None], x, jnp.zeros_like(x))
     a = -jnp.exp(a_log.astype(f32))                                    # (H,)
     da = dt * a                                                        # (B,S,H)
     bh = _to_heads(b_mat, h).astype(f32)
@@ -121,9 +140,13 @@ def ssd_decode_step(x, dt, a_log, b_vec, c_vec, d_skip, dt_bias, state):
 # Causal depthwise conv (the Mamba2 local mixer)
 # ---------------------------------------------------------------------------
 
-def causal_conv(x, w, b, width: int):
-    """x: (B, S, C); w: (width, C); b: (C,). Causal depthwise conv."""
-    pad = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+def causal_conv(x, w, b, width: int, state=None):
+    """x: (B, S, C); w: (width, C); b: (C,). Causal depthwise conv.
+    ``state`` (B, width-1, C): the inputs before ``x`` (zeros if None)."""
+    if state is None:
+        pad = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    else:
+        pad = jnp.concatenate([state.astype(x.dtype), x], axis=1)
     out = jnp.zeros_like(x, dtype=jnp.float32)
     for i in range(width):
         out = out + pad[:, i:i + x.shape[1]].astype(jnp.float32) \
@@ -154,6 +177,7 @@ def _split_proj(zxbcdt, cfg):
     return z, xbc, dt
 
 
+@jax.named_scope("mamba")
 def mamba2_block(x, p, cfg, ssd_fn=ssd_chunked):
     """x: (B, S, d_model) -> (B, S, d_model). Training/prefill path."""
     bsz, s, _ = x.shape
@@ -174,8 +198,44 @@ def mamba2_block(x, p, cfg, ssd_fn=ssd_chunked):
     return jnp.einsum("bsk,kd->bsd", y, p["w_out"]), state
 
 
-def mamba2_decode(x, p, cfg, conv_state, ssm_state):
-    """x: (B, d_model) single token -> (y, conv_state, ssm_state)."""
+@jax.named_scope("mamba")
+def mamba2_chunk(x, p, cfg, conv_state, ssm_state, lengths):
+    """x: (B, C, d_model), one left-aligned fragment per row, against each
+    row's conv window (B, width-1, conv_dim) and SSM state (B, H, P, N):
+    row b advances both over its first ``lengths[b]`` columns only (0
+    leaves them as they were), whatever the width C.  Returns (y,
+    conv_state, ssm_state); y past a row's length is garbage."""
+    bsz, c, _ = x.shape
+    di, n, g = cfg.d_inner, cfg.ssm_state, cfg.ssm_ngroups
+    h, pdim, width = cfg.ssm_nheads, cfg.ssm_headdim, cfg.ssm_conv
+
+    zxbcdt = jnp.einsum("bsd,dk->bsk", x, p["w_in"])
+    z, xbc, dt = _split_proj(zxbcdt, cfg)
+    window = jnp.concatenate([conv_state.astype(xbc.dtype), xbc], axis=1)
+    xbc = jax.nn.silu(causal_conv(xbc, p["conv_w"], p["conv_b"], width,
+                                  state=conv_state))
+    # the window after a row's last valid input: its width-1 inputs
+    # ending there (a row of length 0 keeps the window it came with)
+    idx = lengths[:, None] + jnp.arange(width - 1)[None, :]
+    conv_state = jnp.take_along_axis(window, idx[:, :, None], axis=1)
+    xs = xbc[..., :di].reshape(bsz, c, h, pdim)
+    b_mat = xbc[..., di:di + g * n].reshape(bsz, c, g, n)
+    c_mat = xbc[..., di + g * n:].reshape(bsz, c, g, n)
+    valid = jnp.arange(c)[None, :] < lengths[:, None]
+    y, ssm_state = ssd_chunked(xs, dt, p["a_log"], b_mat, c_mat,
+                               p["d_skip"], p["dt_bias"],
+                               chunk=cfg.ssm_chunk, init_state=ssm_state,
+                               valid=valid)
+    y = layers.gated_rms_norm(y.reshape(bsz, c, di), z, p["norm_w"])
+    return jnp.einsum("bsk,kd->bsd", y, p["w_out"]), conv_state, ssm_state
+
+
+@jax.named_scope("mamba")
+def mamba2_decode(x, p, cfg, conv_state, ssm_state, advance=None):
+    """x: (B, d_model) single token -> (y, conv_state, ssm_state).
+    ``advance`` (B,) bool, optional: the rows whose states the step
+    advances; the others keep theirs."""
+    old_conv, old_ssm = conv_state, ssm_state
     bsz = x.shape[0]
     di, n, g = cfg.d_inner, cfg.ssm_state, cfg.ssm_ngroups
     h, pdim = cfg.ssm_nheads, cfg.ssm_headdim
@@ -193,6 +253,10 @@ def mamba2_decode(x, p, cfg, conv_state, ssm_state):
                                    p["d_skip"], p["dt_bias"], ssm_state)
     y = y.reshape(bsz, di)
     y = layers.gated_rms_norm(y, z, p["norm_w"])
+    if advance is not None:
+        conv_state = jnp.where(advance[:, None, None], conv_state, old_conv)
+        ssm_state = jnp.where(advance[:, None, None, None], ssm_state,
+                              old_ssm)
     return jnp.einsum("bk,kd->bd", y, p["w_out"]), conv_state, ssm_state
 
 

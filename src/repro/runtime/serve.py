@@ -102,6 +102,16 @@ supervisor claws blocks back mid-flight when growth runs the pool dry:
   whole pool, so the maximal-progress request always runs to
   retirement and frees its chain.
 
+**Recurrent state** (the interleaved family, e.g. granite-4.0-h): the
+attention layers page as above, while each Mamba layer keeps a conv
+window and an SSM state per slot beside the pool.  A tick reads and
+writes every slot's state (the solo prefill tick only its one slot's);
+a fragment advances a row's state over its own tokens only.  A slot's
+state is zeroed when a job is seated on it (admission, resume, replayed
+admission), so a resume rebuilds it by replay.  Prefix sharing and
+speculation stay refused: they would need state snapshots at block
+boundaries and a rewind past rejected drafts.
+
 Host Python keeps only what must be host-side: the rent/return ledger
 (`core/supervisor.CorePool`, itself a thin wrapper over the same jittable
 `runtime/pool` transitions), the prefix-hash map, the per-slot fragment
@@ -113,7 +123,8 @@ on the device trace's clock (and which cost about a microsecond each
 when none is): ``serve.tick`` around the whole step (``i``: ticks
 run), ``serve.admit`` (``queued``: frontier length), ``serve.schedule``,
 ``serve.dispatch`` (the uploads and the jitted call; ``family``,
-``decode_rows``, ``frag_tokens``, ``kv_pages``), ``serve.sync`` (the one
+``decode_rows``, ``frag_tokens``, ``kv_pages``, ``state_rows``),
+``serve.sync`` (the one
 ``jax.device_get``; ``compiles``: programs compiled during the
 dispatch), ``serve.emit``, ``serve.preempt`` and ``serve.epilogue``.
 """
@@ -177,9 +188,10 @@ def build_prefill_step(cfg: ArchConfig, max_seq: int,
 
 def build_decode_step(cfg: ArchConfig,
                       rules: Optional[ShardingRules] = None):
-    def decode_step(params, token, cache):
+    def decode_step(params, token, cache, advance=None):
         with use_rules(rules):
-            return model_lib.decode_step(params, token, cache, cfg)
+            return model_lib.decode_step(params, token, cache, cfg,
+                                         advance=advance)
     return decode_step
 
 
@@ -247,6 +259,13 @@ def _merge_rows(new, old, keep_new):
     return jnp.where(keep_new.reshape(shape), new, old)
 
 
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _zero_slot_state(conv, state, slot):
+    """One slot's recurrent state (conv window, SSM state) zeroed in
+    place."""
+    return conv.at[:, slot].set(0), state.at[:, slot].set(0)
+
+
 def build_decode_chunk(cfg: ArchConfig, *, chunk: int, eos_id: int,
                        rules: Optional[ShardingRules] = None,
                        decode_fn: Optional[Callable] = None,
@@ -268,11 +287,16 @@ def build_decode_chunk(cfg: ArchConfig, *, chunk: int, eos_id: int,
     The cache (and block state) is donated: the engine decodes in place.
     """
     decode = decode_fn or build_decode_step(cfg, rules)
+    # per-slot recurrent state, which only the active rows advance
+    state_keys = model_lib.slot_state_leaves(cfg)
 
     def advance(params, st: DecodeState, cache, active, i, emitted):
         """One decode step over every row + retirement bookkeeping."""
         pos0 = cache["pos"]
-        logits, new_cache = decode(params, st.tokens, cache)
+        if state_keys:
+            logits, new_cache = decode(params, st.tokens, cache, active)
+        else:
+            logits, new_cache = decode(params, st.tokens, cache)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         # a retired slot keeps its last token and frozen cache rows /
         # pages: it can never perturb an active one
@@ -284,7 +308,8 @@ def build_decode_chunk(cfg: ArchConfig, *, chunk: int, eos_id: int,
         else:
             # pages are disjoint per chain: an inactive row's write is
             # either dropped (released chain) or rewrites its own cell
-            # with the identical value — only per-row leaves need merge
+            # with the identical value, and the step keeps an inactive
+            # row's recurrent state — only `pos` needs merge
             cache = dict(new_cache,
                          pos=jnp.where(active, new_cache["pos"], pos0))
         emitted = emitted.at[:, i].set(jnp.where(active, tok, NO_TOKEN))
@@ -783,6 +808,9 @@ def build_solo_prefill_tick(cfg: ArchConfig, *, chunk_tokens: int,
     traced scalar: one compile covers every slot.
     """
     W = chunk_tokens
+    # per-slot recurrent leaves (batch axis 1): the tick slices out the
+    # slot's own and writes them back
+    state_keys = model_lib.slot_state_leaves(cfg)
 
     def finish(state: DecodeState, slot, logits, frag_len, frag_last,
                frag_max_new):
@@ -809,6 +837,8 @@ def build_solo_prefill_tick(cfg: ArchConfig, *, chunk_tokens: int,
                 "v": jax.lax.dynamic_slice_in_dim(cache["v"], slot, 1, 1),
                 "pos": jax.lax.dynamic_slice_in_dim(cache["pos"], slot, 1,
                                                     0),
+                **{k: jax.lax.dynamic_slice_in_dim(cache[k], slot, 1, 1)
+                   for k in state_keys},
             }
             with use_rules(rules):
                 logits, sub = model_lib.prefill_chunk(
@@ -820,7 +850,9 @@ def build_solo_prefill_tick(cfg: ArchConfig, *, chunk_tokens: int,
                 v=jax.lax.dynamic_update_slice_in_dim(cache["v"], sub["v"],
                                                       slot, 1),
                 pos=jax.lax.dynamic_update_slice_in_dim(
-                    cache["pos"], sub["pos"], slot, 0))
+                    cache["pos"], sub["pos"], slot, 0),
+                **{k: jax.lax.dynamic_update_slice_in_dim(
+                    cache[k], sub[k], slot, 1) for k in state_keys})
             state, emitted = finish(state, slot, logits, frag_len,
                                     frag_last, frag_max_new)
             return state, cache, emitted
@@ -842,6 +874,8 @@ def build_solo_prefill_tick(cfg: ArchConfig, *, chunk_tokens: int,
             "pos": jax.lax.dynamic_slice_in_dim(cache["pos"], slot, 1, 0),
             "block_tables": jax.lax.dynamic_slice_in_dim(tables, slot, 1,
                                                          0),
+            **{k: jax.lax.dynamic_slice_in_dim(cache[k], slot, 1, 1)
+               for k in state_keys},
         }
         with use_rules(rules):
             logits, sub = model_lib.prefill_chunk(
@@ -849,7 +883,9 @@ def build_solo_prefill_tick(cfg: ArchConfig, *, chunk_tokens: int,
                 skip_until=frag_skip)
         cache = dict(cache, k=sub["k"], v=sub["v"], block_tables=tables,
                      pos=jax.lax.dynamic_update_slice_in_dim(
-                         cache["pos"], sub["pos"], slot, 0))
+                         cache["pos"], sub["pos"], slot, 0),
+                     **{k: jax.lax.dynamic_update_slice_in_dim(
+                         cache[k], sub[k], slot, 1) for k in state_keys})
         state, emitted = finish(state, slot, logits, frag_len, frag_last,
                                 frag_max_new)
         return state, cache, bstate, emitted
@@ -1186,6 +1222,17 @@ class ServingEngine:
                 raise ValueError(
                     f"paged serving supports {model_lib.PAGED_FAMILIES}, "
                     f"not {cfg.family!r}")
+            if cfg.recurrent and not chunked_prefill:
+                raise ValueError(
+                    f"paged serving of {cfg.family!r} needs "
+                    f"chunked_prefill=True: its recurrent state goes in "
+                    f"fragment by fragment, beside the pages")
+            if cfg.recurrent and prefix_sharing:
+                raise ValueError(
+                    f"prefix sharing reuses a prompt prefix's K/V pages, "
+                    f"but {cfg.family!r} also needs each layer's recurrent "
+                    f"state at the prefix's end, and the engine keeps no "
+                    f"state snapshots: pass prefix_sharing=False")
             nb_full = -(-max_seq // block_size)
             if n_blocks is None:       # capacity-equivalent default
                 n_blocks = n_slots * nb_full
@@ -1274,6 +1321,12 @@ class ServingEngine:
                     f"forward: causal attention caches "
                     f"{model_lib.PAGED_FAMILIES} without a frontend only, "
                     f"not {cfg.family!r} (frontend={cfg.frontend!r})")
+            if cfg.recurrent:
+                raise ValueError(
+                    f"speculative decoding rewinds a slot past its "
+                    f"rejected drafts, but {cfg.family!r}'s recurrent "
+                    f"state has already absorbed them, and the engine "
+                    f"keeps no state to rewind to")
             if spec_k < 1:
                 raise ValueError("spec_k must be >= 1")
             if spec_hist < 4:
@@ -1335,6 +1388,16 @@ class ServingEngine:
         # paged: KV pages the decode kernel walks at each chunk's first
         # step, over every row
         self.decode_kv_pages = 0
+        # recurrent state: bytes the ticks read plus wrote, and the
+        # slots zeroed for a new job; _state_row_bytes is one slot's
+        # (0 without recurrent layers), _tick_state_rows the rows of the
+        # last dispatch
+        self.state_bytes = 0
+        self.state_resets = 0
+        self._state_row_bytes = sum(
+            self.cache[k].nbytes // n_slots
+            for k in model_lib.slot_state_leaves(cfg))
+        self._tick_state_rows = 0
         # programs compiled (or loaded from the persistent cache) during
         # step(), and their seconds; a warmed engine compiles none
         self.compiles = 0
@@ -1497,8 +1560,7 @@ class ServingEngine:
                     job.cursor = min(plan.n_shared * self.layout.block_size,
                                      plen - 1)
                     job.registered = plan.n_shared
-                self.cache["pos"] = self.cache["pos"].at[slot].set(
-                    job.cursor)
+                self._seat(slot, job.cursor)
                 self.active[slot] = req
                 self._jobs[slot] = job
                 self.pool.set_phase(slot, pool_lib.PHASE_PREFILL)
@@ -1516,6 +1578,16 @@ class ServingEngine:
                 self.draft_state = draft_lib.seed_slot(
                     self.draft_state, req.slot, req.prompt)
         return consumed
+
+    def _seat(self, slot: int, cursor: int) -> None:
+        """A job starts on ``slot`` at position ``cursor`` (admission,
+        resume, replayed admission); a block with recurrent layers
+        starts it from zero state, which a replay then rebuilds."""
+        self.cache["pos"] = self.cache["pos"].at[slot].set(cursor)
+        if self._state_row_bytes:
+            self.cache["conv"], self.cache["state"] = _zero_slot_state(
+                self.cache["conv"], self.cache["state"], jnp.int32(slot))
+            self.state_resets += 1
 
     def _max_new_eff(self, req: Request, plen: int) -> int:
         """Budget clamp: emitted tokens 2..max_new write at positions
@@ -1897,6 +1969,7 @@ class ServingEngine:
                     jnp.asarray(fcols), jnp.asarray(frent))
         em, active_mask, *block = self._sync(emitted, self.dstate.active)
         self.device_ticks += 1
+        self._count_state()
         finished: list[Request] = []
         with TraceAnnotation("serve.emit"):
             self._refresh_block_mirrors(*block)
@@ -2003,6 +2076,7 @@ class ServingEngine:
         em, active_mask, first, *block = self._sync(
             emitted, self.dstate.active, self._first, stalls=stalls)
         self.device_ticks += 1
+        self._count_state()
         with TraceAnnotation("serve.emit"):
             self._refresh_block_mirrors(*block)
             return self._emit_rows(em, active_mask, first, finishing)
@@ -2012,15 +2086,22 @@ class ServingEngine:
                   kv_pages: int = 0):
         """``serve.dispatch`` around one tick's host->device uploads and
         jitted call, ``frag_lens`` being its prompt fragments' lengths
-        and ``kv_pages`` the KV pages its decode kernel walks;
-        counts the programs compiled meanwhile for the tick's sync."""
+        and ``kv_pages`` the KV pages its decode kernel walks; the span
+        also carries ``state_rows``, the rows whose recurrent state the
+        tick reads and writes.  Counts the programs compiled meanwhile
+        for the tick's sync."""
         frag = 0 if frag_lens is None else int(frag_lens.sum())
         self.frag_tokens += frag
         self.decode_kv_pages += kv_pages
+        # a batched tick reads and writes every slot's recurrent state,
+        # the solo prefill tick its one slot's
+        self._tick_state_rows = 0 if not self._state_row_bytes else \
+            1 if family == "solo_prefill" else self.pool.n
         n0 = _compiled[0]
         with TraceAnnotation("serve.dispatch", family=family,
                              decode_rows=len(self.active) - len(self._jobs),
-                             frag_tokens=frag, kv_pages=kv_pages):
+                             frag_tokens=frag, kv_pages=kv_pages,
+                             state_rows=self._tick_state_rows):
             yield
         self._dispatch_compiles = _compiled[0] - n0
 
@@ -2035,6 +2116,13 @@ class ServingEngine:
             host = jax.device_get(arrays)
         self.host_syncs += 1
         return host
+
+    def _count_state(self, steps: int = 1) -> None:
+        """The last dispatch's recurrent-state traffic, once its sync
+        says how many model steps it ran: each row read and written once
+        a step."""
+        self.state_bytes += \
+            2 * self._tick_state_rows * self._state_row_bytes * steps
 
     def _emit_rows(self, em, active_mask, first,
                    finishing=()) -> list[Request]:
@@ -2205,6 +2293,7 @@ class ServingEngine:
         em, active_mask, first, iters, *block = self._sync(
             emitted, self.dstate.active, self._first, iters, stalls=stalls)
         self.device_ticks += int(iters)
+        self._count_state(int(iters))
         with TraceAnnotation("serve.emit"):
             # the chunk's on-device block growth refreshes the mirrors
             self._refresh_block_mirrors(*block)
@@ -2342,7 +2431,7 @@ class ServingEngine:
                 job.registered = plan.n_shared
             self._park_order.pop(0)
             del self._parked[slot]
-            self.cache["pos"] = self.cache["pos"].at[slot].set(job.cursor)
+            self._seat(slot, job.cursor)
             self.active[slot] = req
             self._jobs[slot] = job
             self.pool.set_phase(slot, pool_lib.PHASE_PREFILL)
@@ -2420,7 +2509,7 @@ class ServingEngine:
             job.cursor = min(plan.n_shared * self.layout.block_size,
                              len(stream) - 1)
             job.registered = plan.n_shared
-        self.cache["pos"] = self.cache["pos"].at[slot].set(job.cursor)
+        self._seat(slot, job.cursor)
         req.slot = slot
         self.active[slot] = req
         self._jobs[slot] = job
@@ -2675,6 +2764,7 @@ class ServingEngine:
         self.device_ticks = self.decode_tokens = 0
         self.frag_tokens = self.compiles = 0
         self.decode_kv_pages = 0
+        self.state_bytes = self.state_resets = 0
         self.compile_s = 0.0
         self.stalls = 0
         self.shared_block_hits = 0
@@ -2697,9 +2787,11 @@ class ServingEngine:
     def sync_stats(self) -> dict:
         """Host-sync economy vs a per-slot-per-tick engine (same run),
         the prompt tokens prefilled through ticks, the KV pages the
-        decode kernel walked at each chunk's first step (paged),
-        and the programs compiled (or loaded from the persistent cache)
-        inside step() with their seconds."""
+        decode kernel walked at each chunk's first step (paged), the
+        recurrent-state bytes the ticks read plus wrote and the slots
+        whose state was zeroed for a new job, and the programs compiled
+        (or loaded from the persistent cache) inside step() with their
+        seconds."""
         tokens = max(1, self.decode_tokens)
         return {
             "host_syncs": self.host_syncs,
@@ -2708,6 +2800,8 @@ class ServingEngine:
             "decode_tokens": self.decode_tokens,
             "frag_tokens": self.frag_tokens,
             "decode_kv_pages": self.decode_kv_pages,
+            "state_bytes": self.state_bytes,
+            "state_resets": self.state_resets,
             "compiles": self.compiles,
             "compile_s": self.compile_s,
             "host_syncs_per_100_tokens": 100.0 * self.host_syncs / tokens,

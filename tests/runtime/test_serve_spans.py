@@ -13,10 +13,13 @@ import glob
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+from repro.configs import get_arch, reduced
+from repro.models import model
 from repro.runtime.serve import Request, ServingEngine
 
 # the jitted tick of each family, by engine attribute
@@ -105,6 +108,29 @@ def traced(request, serve_setup, tmp_path_factory):
                         paged=True, block_size=BLOCK, n_blocks=24,
                         prefix_sharing=False, chunked_prefill=True,
                         prefill_chunk_tokens=8, speculative=request.param)
+    return dict(trace_two_rounds(eng, tmp_path_factory),
+                spec=request.param)
+
+
+HYBRID = reduced(get_arch("granite-4.0-h-micro"), d_model=64, vocab=128,
+                 d_ff=128, head_dim=16, ssm_state=8, ssm_headdim=16,
+                 ssm_chunk=8)
+
+
+@pytest.fixture(scope="module")
+def traced_hybrid(tmp_path_factory):
+    """The same two rounds through a tiny interleaved engine: two Mamba
+    layers, whose recurrent state the ticks carry, and one attention
+    layer."""
+    params = model.init(jax.random.PRNGKey(0), HYBRID, jnp.float32)
+    eng = ServingEngine(params, HYBRID, n_slots=2, max_seq=64, chunk=4,
+                        paged=True, block_size=BLOCK, n_blocks=24,
+                        prefix_sharing=False, chunked_prefill=True,
+                        prefill_chunk_tokens=8)
+    return trace_two_rounds(eng, tmp_path_factory)
+
+
+def trace_two_rounds(eng, tmp_path_factory) -> dict:
     calls = record_families(eng)
     pages = record_live_pages(eng)
     rng = np.random.default_rng(11)
@@ -123,8 +149,8 @@ def traced(request, serve_setup, tmp_path_factory):
             eng.reset_stats()
     finally:
         jax.profiler.stop_trace()
-    return dict(spec=request.param, rounds=rounds, calls=calls,
-                spans=host_spans(directory), stats=eng.sync_stats())
+    return dict(rounds=rounds, calls=calls, spans=host_spans(directory),
+                stats=eng.sync_stats(), n_slots=eng.pool.n)
 
 
 def ticks_of(traced) -> list:
@@ -208,3 +234,43 @@ def test_reset_stats_zeroes_the_new_counters(traced):
     stats = traced["stats"]
     assert (stats["frag_tokens"], stats["decode_kv_pages"],
             stats["compiles"], stats["compile_s"]) == (0, 0, 0, 0.0)
+    assert (stats["state_bytes"], stats["state_resets"]) == (0, 0)
+
+
+def test_state_rows_on_dispatch(traced, traced_hybrid):
+    """``state_rows``: every slot's recurrent state in a batched tick,
+    the one slot's in the solo prefill tick; none without recurrent
+    layers."""
+    assert all(s[3]["state_rows"] == 0 for s in traced["spans"]
+               if s[0] == "serve.dispatch")
+    dispatches = [s[3] for s in traced_hybrid["spans"]
+                  if s[0] == "serve.dispatch"]
+    assert {d["family"] for d in dispatches} == {"solo_prefill", "mixed",
+                                                 "decode"}
+    n = traced_hybrid["n_slots"]
+    for d in dispatches:
+        assert d["state_rows"] == (1 if d["family"] == "solo_prefill"
+                                   else n)
+
+
+def test_state_bytes_and_resets_follow_rows_and_admissions(traced_hybrid):
+    """``state_bytes``: each row a tick carries, read and written once a
+    model step (a decode chunk's steps are the device ticks it ran);
+    ``state_resets``: one zeroed slot per admission."""
+    cfg = HYBRID
+    row = (cfg.n_layers - cfg.n_attn_layers) * 4 * (
+        cfg.ssm_nheads * cfg.ssm_headdim * cfg.ssm_state
+        + (cfg.ssm_conv - 1) * (cfg.d_inner + 2 * cfg.ssm_state))
+    n = traced_hybrid["n_slots"]
+    dispatches = [s[3] for s in traced_hybrid["spans"]
+                  if s[0] == "serve.dispatch"]
+    at = 0
+    for r in traced_hybrid["rounds"]:
+        mine = dispatches[at:at + r["steps"]]
+        at += r["steps"]
+        solo = sum(d["family"] == "solo_prefill" for d in mine)
+        steps = r["stats"]["device_ticks"] - solo
+        assert r["stats"]["state_bytes"] == 2 * row * (n * steps + solo)
+        assert r["stats"]["state_resets"] == len(r["reqs"]) == 2
+    assert (traced_hybrid["stats"]["state_bytes"],
+            traced_hybrid["stats"]["state_resets"]) == (0, 0)
